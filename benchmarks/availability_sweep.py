@@ -314,4 +314,9 @@ def main(argv=None, *, strict: bool = True) -> int:
 
 
 if __name__ == "__main__":
+    import os
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     main()

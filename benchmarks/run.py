@@ -76,6 +76,8 @@ def main() -> int:
         sys.path.insert(0, root)
     suites = tuple(importlib.import_module(f"benchmarks.{name}")
                    for name in SUITE_NAMES)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache(root)
     unknown = _unknown_flags(argv, suites)
     if unknown:
         for flag, close in unknown:

@@ -380,7 +380,6 @@ def _make_chunk_runner(step, carry, *, chunk_steps: int, devices: int,
             step, c, s0 + jnp.arange(chunk_steps, dtype=jnp.int32))
 
     if shard:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec
 
         from ..launch.mesh import make_trials_mesh
@@ -388,10 +387,10 @@ def _make_chunk_runner(step, carry, *, chunk_steps: int, devices: int,
         cspec = tuple(PartitionSpec("trials") for _ in carry)
         yspec = tuple(PartitionSpec(None, "trials")
                       for _ in range(n_outputs))
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             _chunk, mesh=mesh,
             in_specs=(cspec, PartitionSpec()),
-            out_specs=(cspec, yspec), check_rep=False))
+            out_specs=(cspec, yspec), check_vma=False))
     return jax.jit(_chunk)
 
 

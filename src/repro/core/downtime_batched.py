@@ -1198,6 +1198,17 @@ def _make_step(xp, dt_fn, advance, succ, *, n: int, P: int, rf: int,
 # Driver
 # ---------------------------------------------------------------------------
 
+def _pool_partitions(acc, weight=None):
+    """Sum a (B, P, ...) float32 charge accumulator over partitions in
+    float64, in sequence along P.  The C-order copy pins that order: a
+    one-device TPU array can reach the host with its device layout's
+    strides (partitions innermost), and numpy then sums that axis
+    pairwise — a different float64 rounding of the same values, which
+    broke the devices 1-vs-N identity of the latency pools on a v5e."""
+    x = np.ascontiguousarray(acc, dtype=np.float64)
+    return (x if weight is None else x * weight).sum(axis=1)
+
+
 def simulate_downtime_batched(
         *, n: int = 155, partitions: int = 4096, rf: int = 2,
         p: float = 1e-3, downtime: int = 10, trials: int = 8,
@@ -1547,13 +1558,12 @@ def simulate_downtime_batched(
             # summation order independent of backend and device sharding
             # (the dirty fractions persist; the charges restart per chunk)
             lt_ = carry[lat_i:]
-            dup_bp = np.asarray(lt_[1], dtype=np.float64)
-            lat_dup += dup_bp.sum(axis=1)
+            lat_dup += _pool_partitions(lt_[1])
             if lat_wfp is not None:
-                lat_dupw += (dup_bp * lat_wfp[None, :, None]).sum(axis=1)
-            lat_qhist += np.asarray(lt_[2], dtype=np.float64).sum(axis=1)
-            lat_qslo += np.asarray(lt_[3], dtype=np.float64).sum(axis=1)
-            lat_qsum += np.asarray(lt_[4], dtype=np.float64).sum(axis=1)
+                lat_dupw += _pool_partitions(lt_[1], lat_wfp[None, :, None])
+            lat_qhist += _pool_partitions(lt_[2])
+            lat_qslo += _pool_partitions(lt_[3])
+            lat_qsum += _pool_partitions(lt_[4])
             carry = carry[:lat_i] + (lt_[0], lz_nb, lz_hb, lz_bp, lz_bp)
         carry = tuple(acc_reset.get(i, c) for i, c in enumerate(carry))
         if (now >= horizon).all():

@@ -51,15 +51,17 @@ def rng_salts() -> dict:
 
 
 def device_geometry() -> dict:
-    """Backend platform and visible device count as jax actually sees
-    them (the spec records what was *asked for*; this records what the
-    process *got* — e.g. a forced 8-host-device CPU mesh)."""
+    """Backend platform, device kind and visible device count as jax
+    actually sees them (the spec records what was *asked for*; this
+    records what the process *got* — e.g. a forced 8-host-device CPU
+    mesh, or which TPU generation ran the grid)."""
     try:
         import jax
         return {"platform": jax.default_backend(),
+                "device_kind": jax.devices()[0].device_kind,
                 "device_count": jax.device_count()}
     except Exception:  # noqa: BLE001 — provenance must never fail a run
-        return {"platform": None, "device_count": None}
+        return {"platform": None, "device_kind": None, "device_count": None}
 
 
 def build_provenance(spec, *, config_path=None, wall_s=None,

@@ -238,19 +238,24 @@ def downtime_eval_packed(up_words, full_words, *, rf: int, n_real: int,
                                       xp.int32(n_real)))
     qmaj = 2 * nrep > rf
 
-    leader = xp.full(u[0].shape, n_pad, dtype=xp.int32)
-    leader_full = xp.zeros(u[0].shape, dtype=bool)
-    done = None
+    # word 0 seeds the scan (an empty word has lsb 0, so its latest-copy
+    # test is already False).  The bool select is spelled as and/or:
+    # Mosaic has no lowering for a where() whose operands are bool
+    leader = leader_full = done = None
     for k in range(W):
         w = u[k]
         nz = w != xp.uint32(0)
         lsb = w & ((~w) + xp.uint32(1))
-        tz = popcount32(lsb - xp.uint32(1), xp)
-        pick = nz if done is None else (nz & ~done)
-        leader = xp.where(pick, xp.int32(WORD_BITS * k) + tz, leader)
-        leader_full = xp.where(pick, (f[k] & lsb) != xp.uint32(0),
-                               leader_full)
-        done = nz if done is None else (done | nz)
+        rank = xp.int32(WORD_BITS * k) + popcount32(lsb - xp.uint32(1), xp)
+        holds = (f[k] & lsb) != xp.uint32(0)
+        if done is None:
+            leader = xp.where(nz, rank, xp.int32(n_pad))
+            leader_full, done = holds, nz
+            continue
+        pick = nz & ~done
+        leader = xp.where(pick, rank, leader)
+        leader_full = (pick & holds) | (leader_full & ~pick)
+        done = done | nz
     leader = xp.minimum(leader, xp.int32(n_real))
 
     extras = ()

@@ -33,7 +33,7 @@ recruit/active as (B, P).  Validity masking uses compile-time prefix-mask
 constants, so there is no `valid` input tensor at all.
 
 ops.step_eval dispatches here for StepSpec(packed=True) on the pallas
-backend; block sizes come from ops.autotune_step_blocks (2-D fused
+backend; block sizes come from ops.autotune_fused_blocks (2-D fused
 autotuner with fused-kernel VMEM accounting).  Interpret mode runs the
 same kernel on CPU for the CI smoke rows and the bit-identity matrix.
 """
@@ -46,9 +46,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from . import bitpack
+from .pac_eval import node_count_tile, tile_rule_error
 
 
-def _check_tiles(B: int, P: int, block_t: int, block_p: int):
+def _check_tiles(B: int, P: int, block_t: int, block_p: int, *,
+                 interpret: bool):
     if B % block_t:
         raise ValueError(
             f"block_t={block_t} must tile the trial count B={B} exactly — "
@@ -57,6 +59,10 @@ def _check_tiles(B: int, P: int, block_t: int, block_p: int):
         raise ValueError(
             f"block_p={block_p} must tile the partition count P={P} "
             "exactly — pick a candidate from ops.fused_block_candidates")
+    err = None if interpret else tile_rule_error(
+        (B, P), (block_t, block_p), what="fused step")
+    if err:
+        raise ValueError(err)
 
 
 def _fused_pac_kernel(upw_ref, fullw_ref, lark_ref, maj_ref, crepsw_ref, *,
@@ -80,7 +86,7 @@ def fused_pac_eval(upw, fullw, *, rf: int, voters: int, n_real: int,
     B, W, P = upw.shape
     block_t = min(block_t, B)
     block_p = min(block_p, P)
-    _check_tiles(B, P, block_t, block_p)
+    _check_tiles(B, P, block_t, block_p, interpret=interpret)
     kernel = functools.partial(_fused_pac_kernel, rf=rf, voters=voters,
                                n_real=n_real, W=W)
     word_spec = pl.BlockSpec((block_t, W, block_p), lambda i, j: (i, 0, j))
@@ -99,29 +105,9 @@ def fused_pac_eval(upw, fullw, *, rf: int, voters: int, n_real: int,
     )(upw, fullw)
 
 
-def _node_count_block(rec, act, n_lanes: int, bp_cols: int):
-    """(bt, bp) recruit ids + active mask -> (bt, n_lanes) int32 one-hot
-    accumulation over this tile's partition columns (the same
-    compare-and-sum loop as pac_eval._node_count_kernel, folded into the
-    fused body).  Ids outside [0, n_lanes) match no lane; ids in
-    [n_real, n_lanes) land in padding columns the wrapper slices off."""
-    bt = rec.shape[0]
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (bt, n_lanes), 1)
-
-    def body(j, cnt):
-        rec_j = jax.lax.dynamic_slice_in_dim(rec, j, 1, axis=1)
-        act_j = jax.lax.dynamic_slice_in_dim(act, j, 1, axis=1) \
-            .astype(jnp.int32)
-        return cnt + jnp.where(lanes == rec_j, act_j, 0)
-
-    return jax.lax.fori_loop(
-        0, bp_cols, body, jnp.zeros((bt, n_lanes), dtype=jnp.int32))
-
-
 def _fused_downtime_kernel(refs, *, rf: int, n_real: int, W: int,
                            with_roster: bool, with_counts: bool,
-                           with_repmask: bool, with_rleader: bool,
-                           n_lanes: int, bp_cols: int):
+                           with_repmask: bool, with_rleader: bool):
     it = iter(refs)
     upw_ref, fullw_ref = next(it), next(it)
     roster_ref = next(it) if with_roster else None
@@ -169,8 +155,8 @@ def _fused_downtime_kernel(refs, *, rf: int, n_real: int, W: int,
         def _init():
             cnt_ref[...] = jnp.zeros(cnt_ref.shape, dtype=jnp.int32)
 
-        cnt_ref[...] = cnt_ref[...] + _node_count_block(
-            rec_ref[...].astype(jnp.int32), act_ref[...], n_lanes, bp_cols)
+        cnt_ref[...] = cnt_ref[...] + node_count_tile(
+            rec_ref[...], act_ref[...], cnt_ref.shape[1])
 
 
 def fused_downtime_eval(upw, fullw, *, rf: int, n_real: int, block_t: int,
@@ -198,7 +184,7 @@ def fused_downtime_eval(upw, fullw, *, rf: int, n_real: int, block_t: int,
     B, W, P = upw.shape
     block_t = min(block_t, B)
     block_p = min(block_p, P)
-    _check_tiles(B, P, block_t, block_p)
+    _check_tiles(B, P, block_t, block_p, interpret=interpret)
     with_roster = roster is not None
     with_counts = recruit is not None
     if with_counts and active is None:
@@ -215,7 +201,7 @@ def fused_downtime_eval(upw, fullw, *, rf: int, n_real: int, block_t: int,
         operands.append(roster.astype(jnp.int32))
     if with_counts:
         in_specs += [row_spec, row_spec]
-        operands += [recruit.astype(jnp.int32), active]
+        operands += [recruit.astype(jnp.int32), active.astype(jnp.int32)]
     n_extra = int(want_repmask) + int(want_rleader)
     out_specs = [row_spec] * (5 + n_extra) + [word_spec]
     out_shape = [
@@ -236,8 +222,7 @@ def fused_downtime_eval(upw, fullw, *, rf: int, n_real: int, block_t: int,
     kernel = functools.partial(
         _fused_downtime_kernel, rf=rf, n_real=n_real, W=W,
         with_roster=with_roster, with_counts=with_counts,
-        with_repmask=want_repmask, with_rleader=want_rleader,
-        n_lanes=n_lanes, bp_cols=block_p)
+        with_repmask=want_repmask, with_rleader=want_rleader)
 
     def kernel_splat(*refs):
         kernel(refs)

@@ -50,19 +50,35 @@ import numpy as np
 
 from . import bitpack, ref
 from . import latency as _latency
+from .pac_eval import lane_block, legal_blocks, trial_block
 
 _FORCE = os.environ.get("REPRO_FORCE_PALLAS", "")
 
 PAC_BACKENDS = ("numpy", "jax", "pallas")
 
 
+def pallas_interpret() -> bool:
+    """Whether the Monte Carlo Pallas kernels run interpreted: False on a
+    TPU (compiled by Mosaic), True on the CPU (the test and CI
+    platform).  Any other platform raises — a kernel must never fall
+    back quietly to a path that hides the device."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas Monte Carlo kernels compile for a TPU or interpret on "
+        f"the CPU; JAX's default backend is {platform!r}")
+
+
 def _mode() -> str:
-    """'kernel' | 'interpret' | 'ref'."""
+    """'kernel' | 'interpret' | 'ref' (interpret only when forced)."""
     if _FORCE == "interpret":
         return "interpret"
     if _FORCE == "ref":
         return "ref"
-    return "kernel" if jax.default_backend() == "tpu" else "ref"
+    return "ref" if pallas_interpret() else "kernel"
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
@@ -124,12 +140,9 @@ from .pac_np import (downtime_eval_rank_np,  # noqa: E402  (re-export)
                      pac_eval_rank_np, rebuild_node_counts_np)
 
 
-def _pallas_block_p(R: int) -> int:
-    """Largest power-of-two block size <= 256 that divides the row count."""
-    bp = 1
-    while bp < 256 and R % (bp * 2) == 0:
-        bp *= 2
-    return bp
+#: VMEM (bytes, one copy of every block) a kernel tile may hold — half
+#: the 16 MiB Mosaic scopes on a v5e, since Pallas double-buffers blocks
+VMEM_BUDGET = 8 * 2 ** 20
 
 
 def _pac_lane_pad(n_pad: int) -> int:
@@ -139,28 +152,40 @@ def _pac_lane_pad(n_pad: int) -> int:
 
 def pac_vmem_bytes(block_p: int, n_pad: int) -> int:
     """VMEM the PAC kernel holds live for one (block_p, n_lanes) block:
-    three int32 input tiles (up, full, valid), the int32 cumsum/creps
-    working tile, and the bool outputs — the budget the autotuner's
-    candidate enumeration respects."""
+    three int32 tiles (up, full, creps), the float32 prefix-count tile
+    and its (n_lanes, n_lanes) triangular operand, and the lane-padded
+    (block_p, 1) row outputs — the budget the autotuner's candidate
+    enumeration respects."""
     n_lanes = _pac_lane_pad(n_pad)
-    return block_p * n_lanes * 4 * 4 + block_p * (2 + n_lanes)
+    return (block_p * n_lanes * 4 * 4 + n_lanes * n_lanes * 4
+            + 2 * block_p * 128 * 4)
+
+
+def _pallas_block_p(R: int, n_pad: int = 128) -> int:
+    """Default row block of the unpacked kernels: the largest legal block
+    <= 256 (a power-of-two multiple of 8 dividing R, or all R rows)."""
+    bp = max(legal_blocks(R, 8, 256))
+    if pac_vmem_bytes(bp, n_pad) > VMEM_BUDGET:
+        raise ValueError(f"no legal row block tiles R={R} rows within the "
+                         f"VMEM budget (largest legal block {bp})")
+    return bp
 
 
 def block_p_candidates(R: int, n_pad: int, *, max_block: int = 1024,
-                       vmem_limit_bytes: int = 8 * 2 ** 20):
-    """Power-of-two block_p values that tile R rows within the VMEM budget.
+                       vmem_limit_bytes: int = VMEM_BUDGET):
+    """Legal block_p values (legal_blocks) that tile R rows within the
+    VMEM budget; raises when there is none.
 
     Deterministic pure function of its arguments — the autotuner measures
     exactly this set, so two runs on the same shape always race the same
     candidates.
     """
-    cands = []
-    bp = 8
-    while bp <= min(R, max_block):
-        if R % bp == 0 and pac_vmem_bytes(bp, n_pad) <= vmem_limit_bytes:
-            cands.append(bp)
-        bp *= 2
-    return tuple(cands) or (_pallas_block_p(R),)
+    cands = tuple(bp for bp in legal_blocks(R, 8, max_block)
+                  if pac_vmem_bytes(bp, n_pad) <= vmem_limit_bytes)
+    if not cands:
+        raise ValueError(f"no legal block_p tiles R={R} rows within "
+                         f"{vmem_limit_bytes} bytes of VMEM")
+    return cands
 
 
 @dataclass(frozen=True)
@@ -194,7 +219,7 @@ def _measure_pac_block(R: int, n_pad: int, bp: int, *, rf: int, voters: int,
            + jnp.arange(n_lanes, dtype=jnp.uint32)[None, :])
     up = (idx * jnp.uint32(2654435761) % jnp.uint32(97)) < 90   # ~93% up,
     full = (idx * jnp.uint32(40503) % jnp.uint32(89)) < 30      # fixed pattern
-    interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret()
     if kernel == "pac":
         fn = jax.jit(functools.partial(
             pk.pac_eval, rf=rf, voters=voters, n_real=n_real, block_p=bp,
@@ -265,8 +290,9 @@ def autotune_block_p(R: int, n_pad: int, *, rf: int, voters: int,
     if measure is None and key in _AUTOTUNE_CACHE:
         return _AUTOTUNE_CACHE[key]
     if measure is None:
-        if jax.default_backend() != "tpu" and not force:
-            res = AutotuneResult(block_p=_pallas_block_p(R), timings_us={},
+        if pallas_interpret() and not force:
+            res = AutotuneResult(block_p=_pallas_block_p(R, n_pad),
+                                 timings_us={},
                                  source="heuristic-fallback")
             _AUTOTUNE_CACHE[key] = res
             return res
@@ -312,10 +338,11 @@ def _pac_eval_unpacked(up_succ, full_succ, *, rf: int, voters: int,
         if lanes:                                 # multiple for the VPU tile
             up_succ = jnp.pad(up_succ, ((0, 0), (0, lanes)))
             full_succ = jnp.pad(full_succ, ((0, 0), (0, lanes)))
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
         lark, maj, creps = pk.pac_eval(up_succ, full_succ, rf=rf,
                                        voters=voters, n_real=n_real,
-                                       block_p=block_p or _pallas_block_p(R),
+                                       block_p=block_p
+                                       or _pallas_block_p(R, n_pad),
                                        interpret=interpret)
         return lark, maj, creps[:, :n_pad]
     raise ValueError(f"unknown PAC backend {backend!r}; "
@@ -376,10 +403,11 @@ def _downtime_eval_unpacked(up_succ, full_succ, *, rf: int, n_real: int,
             roster = jnp.pad(roster.astype(jnp.int32),
                              ((0, 0), (0, rpad)),
                              constant_values=n_pad + lanes)
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
         outs = pk.downtime_eval(
             up_succ, full_succ, rf=rf, n_real=n_real,
-            block_p=block_p or _pallas_block_p(R), interpret=interpret,
+            block_p=block_p or _pallas_block_p(R, n_pad),
+            interpret=interpret,
             roster=roster, want_repmask=want_repmask,
             want_rleader=want_rleader)
         return tuple(outs[:-1]) + (outs[-1][:, :n_pad],)
@@ -401,8 +429,8 @@ def _rebuild_node_counts_impl(recruit, active, *, n_real: int,
     mix), so it commutes with trials-axis sharding; the 8-device proof
     lives in tests/test_sharded.py.  All three backends are bit-identical:
     the numpy/jnp implementations scatter-add, the Pallas kernel
-    (kernels/pac_eval.py: node_count) accumulates one-hot compares over
-    the partition columns — pure integer work either way.
+    (kernels/pac_eval.py: node_count) sums one-hot products over the
+    partition columns — exact counts either way.
     """
     if backend == "numpy":
         return rebuild_node_counts_np(recruit, active, n_real=n_real)
@@ -411,7 +439,7 @@ def _rebuild_node_counts_impl(recruit, active, *, n_real: int,
     if backend == "pallas":
         from . import pac_eval as pk
         counts = pk.node_count(recruit, active, n_real=n_real,
-                               interpret=jax.default_backend() != "tpu")
+                               interpret=pallas_interpret())
         return counts[:, :n_real]
     raise ValueError(f"unknown PAC backend {backend!r}; "
                      f"expected one of {PAC_BACKENDS}")
@@ -455,22 +483,20 @@ def client_latency_step(dirty, dt_i, avail, qok, rem, *, pow_tables, kf,
         return jax.lax.optimization_barrier(out)
     if backend == "pallas":
         from . import pac_eval as pk
-        B, P, NB = dirty.shape
-        R = B * P
-        decay = _latency.decay_from_dt(dt_i, pow_tables, jnp)
+        # the kernel runs bucket-major (B, NB, P): partitions on lanes.
+        # The decay chain is the identical elementwise jnp product, taken
+        # over the transposed tables
+        decay = _latency.decay_from_dt(
+            dt_i, jnp.swapaxes(pow_tables, 1, 2), jnp)
         nd, dup, qh, qs, qq = pk.latency_charge(
-            dirty.reshape(R, NB), decay.reshape(R, NB),
-            avail.reshape(R), qok.reshape(R), rem.reshape(R),
-            jnp.broadcast_to(dt_i[:, None], (B, P)).reshape(R),
-            jnp.broadcast_to(lamw[None, :], (B, P)).reshape(R),
+            jnp.swapaxes(dirty, 1, 2), decay, avail, qok, rem, dt_i, lamw,
             kf, nbins=nbins, slo_ticks=slo_ticks,
-            interpret=jax.default_backend() != "tpu")
+            interpret=pallas_interpret())
         # same FMA-contraction pin as the jax branch: in interpret mode
         # the kernel body inlines into the surrounding jit
         return jax.lax.optimization_barrier(
-            (nd.reshape(B, P, NB), dup.reshape(B, P, NB),
-             qh.reshape(B, P, nbins), qs.reshape(B, P),
-             qq.reshape(B, P)))
+            (jnp.swapaxes(nd, 1, 2), jnp.swapaxes(dup, 1, 2),
+             jnp.swapaxes(qh, 1, 2), qs, qq))
     raise ValueError(f"unknown PAC backend {backend!r}; "
                      f"expected one of {PAC_BACKENDS}")
 
@@ -607,14 +633,6 @@ class StepOutputs(NamedTuple):
     rleader: object = None
 
 
-def _fused_block_t(B: int) -> int:
-    """Heuristic trial-block: largest power of two <= 8 dividing B."""
-    bt = 1
-    while bt < 8 and B % (bt * 2) == 0:
-        bt *= 2
-    return bt
-
-
 def _packed_planes(words, xp):
     W = words.shape[1]
     return [words[:, k, :] for k in range(W)]
@@ -702,9 +720,12 @@ def step_eval(spec: StepSpec, up, full, *, roster=None, recruit=None,
     B, W, P = up.shape
     if backend == "pallas":
         from . import fused_step
-        interpret = jax.default_backend() != "tpu"
-        bt = block_t or _fused_block_t(B)
-        bp = block_p or _pallas_block_p(P)
+        interpret = pallas_interpret()
+        bt, bp = block_t, block_p
+        if not (bt and bp):
+            t0, p0 = fused_default_blocks(B, P, spec.n_real, rf=spec.rf,
+                                          kernel=spec.fused_kernel)
+            bt, bp = bt or t0, bp or p0
         if spec.metric == "availability":
             lark, maj, crepsw = fused_step.fused_pac_eval(
                 up, full, rf=spec.rf, voters=spec.resolved_voters,
@@ -777,29 +798,40 @@ def fused_vmem_bytes(block_t: int, block_p: int, n_pad: int, *,
         rows += block_t * rf * block_p * 4            # roster tile
         rows += 2 * block_t * block_p * 4             # recruit + active
         rows += block_t * n_lanes * 4                 # counts accumulator
+        rows += n_lanes * block_p * 4                 # one-hot operand
     return words + rows
 
 
 def fused_block_candidates(B: int, P: int, n_pad: int, *, rf: int = 3,
                            kernel: str = "fused_pac",
                            max_block_t: int = 16, max_block_p: int = 1024,
-                           vmem_limit_bytes: int = 8 * 2 ** 20):
-    """Power-of-two (block_t, block_p) pairs that tile (B, P) within the
-    fused-kernel VMEM budget — deterministic pure function of the shape,
-    like block_p_candidates."""
-    cands = []
-    bt = 1
-    while bt <= min(B, max_block_t):
-        if B % bt == 0:
-            bp = 8
-            while bp <= min(P, max_block_p):
-                if P % bp == 0 and fused_vmem_bytes(
-                        bt, bp, n_pad, rf=rf,
-                        kernel=kernel) <= vmem_limit_bytes:
-                    cands.append((bt, bp))
-                bp *= 2
-        bt *= 2
-    return tuple(cands) or ((_fused_block_t(B), _pallas_block_p(P)),)
+                           vmem_limit_bytes: int = VMEM_BUDGET):
+    """Legal (block_t, block_p) pairs — block_t on the (8-sublane) trial
+    axis, block_p on the (128-lane) partition axis, per legal_blocks —
+    that tile (B, P) within the fused-kernel VMEM budget; raises when
+    there is none.  Deterministic pure function of the shape, like
+    block_p_candidates."""
+    cands = tuple(
+        (bt, bp) for bt in legal_blocks(B, 8, max_block_t)
+        for bp in legal_blocks(P, 128, max_block_p)
+        if fused_vmem_bytes(bt, bp, n_pad, rf=rf,
+                            kernel=kernel) <= vmem_limit_bytes)
+    if not cands:
+        raise ValueError(f"no legal fused tile covers (B={B}, P={P}) "
+                         f"within {vmem_limit_bytes} bytes of VMEM")
+    return cands
+
+
+def fused_default_blocks(B: int, P: int, n_pad: int, *, rf: int = 3,
+                         kernel: str = "fused_pac") -> Tuple[int, int]:
+    """The untuned fused tile: 8 trials (or all B) x the largest legal
+    lane block <= 256 (or all P) — legal for any (B, P); raises when it
+    overflows the VMEM budget."""
+    bt, bp = trial_block(B), lane_block(P, 256)
+    if fused_vmem_bytes(bt, bp, n_pad, rf=rf, kernel=kernel) > VMEM_BUDGET:
+        raise ValueError(f"no legal fused tile covers (B={B}, P={P}) "
+                         f"within the VMEM budget (default {bt}x{bp})")
+    return bt, bp
 
 
 @dataclass(frozen=True)
@@ -828,7 +860,7 @@ def _measure_fused_block(B: int, P: int, n_pad: int, bt: int, bp: int, *,
         .reshape(B, P, n_pad)
     upw = jnp.moveaxis(bitpack.pack_words(up, jnp), -1, 1)
     fullw = jnp.moveaxis(bitpack.pack_words(full, jnp), -1, 1)
-    interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret()
     if kernel == "fused_pac":
         fn = jax.jit(functools.partial(
             fused_step.fused_pac_eval, rf=rf, voters=voters,
@@ -897,9 +929,9 @@ def autotune_fused_blocks(B: int, P: int, n_pad: int, *, rf: int,
     if measure is None and key in _AUTOTUNE_CACHE:
         return _AUTOTUNE_CACHE[key]
     if measure is None:
-        if jax.default_backend() != "tpu" and not force:
-            res = FusedAutotuneResult(block_t=_fused_block_t(B),
-                                      block_p=_pallas_block_p(P),
+        if pallas_interpret() and not force:
+            bt, bp = fused_default_blocks(B, P, n_pad, rf=rf, kernel=kernel)
+            res = FusedAutotuneResult(block_t=bt, block_p=bp,
                                       timings_us={},
                                       source="heuristic-fallback")
             _AUTOTUNE_CACHE[key] = res
@@ -935,8 +967,9 @@ def step_hbm_bytes(spec: StepSpec, B: int, P: int, n_pad: int) -> dict:
     n_lanes = _pac_lane_pad(n_pad)
     reconfig = spec.metric == "downtime" and spec.rebuild_model == "reconfig"
     rows_out = (2 if spec.metric == "availability" else 5) * R * 4
-    # boolean path: pac_eval.py materializes up/full/valid as int32 lanes
-    unfused = 3 * R * n_lanes * 4 + R * n_lanes * 4 + rows_out
+    # boolean path: pac_eval.py reads up/full and writes creps as lane
+    # tiles (bool arrays cross the kernel boundary as int32)
+    unfused = 3 * R * n_lanes * 4 + rows_out
     if reconfig:
         rf_pad = spec.rf + (-spec.rf % 128)
         unfused += R * rf_pad * 4                       # roster tile

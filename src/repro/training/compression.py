@@ -14,7 +14,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -44,9 +43,9 @@ def compressed_pod_psum(grads, err, mesh: Mesh):
             return summed / npod, new_e
 
         spec = P(*([None] * g.ndim))
-        return shard_map(inner, mesh=mesh,
-                         in_specs=(spec, spec), out_specs=(spec, spec),
-                         check_rep=False)(g, e)
+        return jax.shard_map(inner, mesh=mesh,
+                             in_specs=(spec, spec), out_specs=(spec, spec),
+                             check_vma=False)(g, e)
 
     flat_g, treedef = jax.tree.flatten(grads)
     flat_e = treedef.flatten_up_to(err)

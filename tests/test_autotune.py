@@ -106,12 +106,15 @@ def test_fused_candidates_pure_function_of_shape_and_budget():
         assert fused_vmem_bytes(bt, bp, 160, rf=3,
                                 kernel="fused_downtime_roster") \
             <= 8 * 2 ** 20
-    # a tighter budget prunes the fat tiles but never empties the set
-    floor = fused_vmem_bytes(1, 8, 160, rf=3, kernel="fused_pac")
+    # a tighter budget prunes the fat tiles down to the smallest legal
+    # one; below that there is no legal tiling, and that is an error
+    floor = fused_vmem_bytes(8, 128, 160, rf=3, kernel="fused_pac")
     small = fused_block_candidates(8, 4096, 160, rf=3,
                                    vmem_limit_bytes=floor)
-    assert small
-    assert max(bt * bp for bt, bp in small) <= 8
+    assert small == ((8, 128),)
+    with pytest.raises(ValueError, match="no legal fused tile"):
+        fused_block_candidates(8, 4096, 160, rf=3,
+                               vmem_limit_bytes=floor - 1)
 
 
 def test_fused_autotune_ties_break_toward_the_smaller_tile():

@@ -422,3 +422,26 @@ def test_new_knobs_backend_matrix_bit_identical():
         assert base.lat_hermes == other.lat_hermes
         assert np.array_equal(base.slo_curve_quorum,
                               other.slo_curve_quorum)
+
+
+def test_partition_pool_sums_in_sequence_whatever_the_layout():
+    """Chunk drains pool the float32 charge accumulators over partitions
+    in float64, in sequence along P, whatever strides the device array
+    reaches the host with (a one-device TPU array arrives partitions-
+    innermost, where a plain numpy sum would go pairwise and round the
+    same values differently)."""
+    from repro.core.downtime_batched import _pool_partitions
+    rng = np.random.default_rng(5)
+    x = (rng.random((3, 300, 4)) ** 20 * 1000).astype(np.float32)
+    strided = np.moveaxis(np.ascontiguousarray(np.moveaxis(x, 1, -1)), -1, 1)
+    assert not strided.flags.c_contiguous
+    want = np.zeros((3, 4))
+    for p in range(x.shape[1]):
+        want += x[:, p].astype(np.float64)
+    assert np.array_equal(_pool_partitions(x), want)
+    assert np.array_equal(_pool_partitions(strided), want)
+    w = rng.random((1, 300, 1))
+    want_w = np.zeros((3, 4))
+    for p in range(x.shape[1]):
+        want_w += x[:, p].astype(np.float64) * w[:, p]
+    assert np.array_equal(_pool_partitions(strided, w), want_w)

@@ -243,6 +243,8 @@ def test_runner_streams_events_and_stamps_provenance(tmp_path, monkeypatch):
     assert prov["requested"] == {"backend": "numpy", "devices": 1,
                                  "trials": 2}
     assert prov["wall_s"] is not None and prov["started_unix"] > 0
+    assert set(prov["observed"]) == {"platform", "device_kind",
+                                     "device_count"}
     # the embedded spec reproduces the spec exactly (lossless meta)
     assert ExperimentSpec.create(**meta["spec"]) == spec
     # rows in the document are json-safe (no non-finite floats)
