@@ -27,9 +27,10 @@ jax one none.  One fused autotune race must report source="measured".
 devices=4 (trials sharded over a "trials" mesh) against devices=1; the
 results must match bit for bit.
 
-Lines before the last are labels for a reader (per phase: compile
-seconds, run seconds, the device kind, the tiles), not benchmark
-metrics.  The last line is one JSON object: {"ok": true, "device":
+Lines before the last are labels for a reader (the device kind, the
+tiles, each comparison), not benchmark metrics; the engines' own
+profiler spans time compile and chunks (docs/ARCHITECTURE.md, "Tracing
+a run").  The last line is one JSON object: {"ok": true, "device":
 {"platform": "tpu", "kind": ..., "count": ...}}.  Without a TPU, or
 without the repo's src/ next to this file, it exits non-zero and prints
 no result.  One process holds the chip; nothing is run in a child.
@@ -58,30 +59,24 @@ class SmokeFailure(Exception):
 class ChunkProbe:
     """Wraps the engines' chunk-runner factory: the first call of each
     runner lowers and compiles the jitted chunk program on the run's own
-    arguments (timed, and its HLO kept for the kernel check), and every
-    call runs that one executable (timed)."""
+    arguments (its HLO kept for the kernel check), and every call runs
+    that one executable."""
 
-    def __init__(self, jax):
-        self.jax = jax
+    def __init__(self):
         self.runs = []
 
     def wrap(self, make):
         def make_runner(step, carry, **kw):
             fn = make(step, carry, **kw)
-            rec = {"compile_s": None, "chunk_s": [], "kernel": None}
+            rec = {"kernel": None}
             self.runs.append(rec)
             exe = []
 
             def run(c, s0):
                 if not exe:
-                    t = time.perf_counter()
                     exe.append(fn.lower(c, s0).compile())
-                    rec["compile_s"] = time.perf_counter() - t
                     rec["kernel"] = "tpu_custom_call" in exe[0].as_text()
-                t = time.perf_counter()
-                out = self.jax.block_until_ready(exe[0](c, s0))
-                rec["chunk_s"].append(time.perf_counter() - t)
-                return out
+                return exe[0](c, s0)
             return run
         return make_runner
 
@@ -155,12 +150,6 @@ def _run(probe, name, fn, kw, **variant):
     if not runs:
         raise SmokeFailure(f"{name}: no chunk program ran")
     label = ",".join(f"{k}={v}" for k, v in variant.items())
-    for i, r in enumerate(runs):
-        chunk = sorted(r["chunk_s"])
-        print(f"# {name} [{label}] program {i}: compile_s="
-              f"{r['compile_s']!r} run_s={sum(chunk)!r} chunks="
-              f"{len(chunk)} median_chunk_s={chunk[len(chunk) // 2]!r}",
-              flush=True)
     want = variant.get("backend") == "pallas"
     for r in runs:
         if r["kernel"] is not want:
@@ -246,7 +235,7 @@ def main(argv=None) -> int:
           flush=True)
 
     from repro.core import availability_batched, downtime_batched
-    probe = ChunkProbe(jax)
+    probe = ChunkProbe()
     runner = probe.wrap(availability_batched._make_chunk_runner)
     availability_batched._make_chunk_runner = runner
     downtime_batched._make_chunk_runner = runner

@@ -72,6 +72,7 @@ import numpy as np
 from ..kernels import bitpack
 from ..kernels.ops import PAC_BACKENDS, StepSpec, step_eval
 from .availability import t975
+from .stages import annotate, next_call, span, stage
 from .succession import succession_matrix_fast
 
 _GEO_SALT = 0x9E3779B9
@@ -216,36 +217,38 @@ def _make_node_advance(xp, *, n: int, horizon: int, dt_vec, geo_masks,
     same trajectory, and sharded runs match single-device bit for bit.
     """
     def advance(now, up, ev_t, rr_t, rr_idx, lane0, s):
-        node_next = xp.min(ev_t, axis=1)                     # (B,)
-        t_next = node_next if not restart_period else \
-            xp.minimum(node_next, rr_t)
-        active = t_next < horizon
-        t_clamp = xp.minimum(t_next, xp.int32(horizon))
-        dt = (t_clamp - now).astype(xp.float32)
+        with stage(xp, "lark_node_advance"):
+            node_next = xp.min(ev_t, axis=1)                 # (B,)
+            t_next = node_next if not restart_period else \
+                xp.minimum(node_next, rr_t)
+            active = t_next < horizon
+            t_clamp = xp.minimum(t_next, xp.int32(horizon))
+            dt = (t_clamp - now).astype(xp.float32)
 
-        hit = (ev_t == t_next[:, None]) & active[:, None]
-        fail_hit = hit & up
-        rec_hit = hit & ~up
-        if restart_period:
-            rr_hit = active & (rr_t == t_next)
-            offs = (xp.arange(n, dtype=xp.int32)[None, :]
-                    - rr_idx[:, None]) % n
-            tgt = offs < wave_width
-            fail_hit = fail_hit | (tgt & up & rr_hit[:, None])
-            rr_idx = xp.where(rr_hit, (rr_idx + wave_width) % n, rr_idx)
-            rr_t = xp.where(rr_hit, rr_t + restart_period, rr_t)
-        s_u32 = xp.asarray(s).astype(xp.uint32)
-        if pair_fail_prob > 0.0:
-            u2 = _uniforms(seed_mix, s_u32, _PAIR_SALT, lane0, n, xp)
-            pf = fail_hit[:, pair_perm] & up & ~fail_hit & ~rec_hit & \
-                (u2 < pair_fail_prob)
-            fail_hit = fail_hit | pf
-        up = (up & ~fail_hit) | rec_hit
-        geo = _geometric_multi(
-            _uniforms(seed_mix, s_u32, _GEO_SALT, lane0, n, xp),
-            geo_masks, geo_tables, xp)
-        ev_t = xp.where(fail_hit, t_clamp[:, None] + dt_vec[None, :],
-                        xp.where(rec_hit, t_clamp[:, None] + geo, ev_t))
+            hit = (ev_t == t_next[:, None]) & active[:, None]
+            fail_hit = hit & up
+            rec_hit = hit & ~up
+            if restart_period:
+                rr_hit = active & (rr_t == t_next)
+                offs = (xp.arange(n, dtype=xp.int32)[None, :]
+                        - rr_idx[:, None]) % n
+                tgt = offs < wave_width
+                fail_hit = fail_hit | (tgt & up & rr_hit[:, None])
+                rr_idx = xp.where(rr_hit, (rr_idx + wave_width) % n,
+                                  rr_idx)
+                rr_t = xp.where(rr_hit, rr_t + restart_period, rr_t)
+            s_u32 = xp.asarray(s).astype(xp.uint32)
+            if pair_fail_prob > 0.0:
+                u2 = _uniforms(seed_mix, s_u32, _PAIR_SALT, lane0, n, xp)
+                pf = fail_hit[:, pair_perm] & up & ~fail_hit & ~rec_hit & \
+                    (u2 < pair_fail_prob)
+                fail_hit = fail_hit | pf
+            up = (up & ~fail_hit) | rec_hit
+            geo = _geometric_multi(
+                _uniforms(seed_mix, s_u32, _GEO_SALT, lane0, n, xp),
+                geo_masks, geo_tables, xp)
+            ev_t = xp.where(fail_hit, t_clamp[:, None] + dt_vec[None, :],
+                            xp.where(rec_hit, t_clamp[:, None] + geo, ev_t))
         return t_clamp, dt, active, up, ev_t, rr_t, rr_idx
     return advance
 
@@ -424,34 +427,42 @@ def _make_step(xp, pac_fn, succ, *, n: int, P: int, horizon: int,
         B = up.shape[0]               # local trials (a shard of the batch)
         t_clamp, dt, active, up, ev_t, rr_t, rr_idx = advance(
             now, up, ev_t, rr_t, rr_idx, lane0, s)
-        lpt = lpt + xp.sum(dnl, axis=1).astype(xp.float32) * dt
-        mpt = mpt + xp.sum(dnm, axis=1).astype(xp.float32) * dt
+        with stage(xp, "lark_protocols"):
+            lpt = lpt + xp.sum(dnl, axis=1).astype(xp.float32) * dt
+            mpt = mpt + xp.sum(dnm, axis=1).astype(xp.float32) * dt
         now = t_clamp
 
         if packed:
             # packed variant: the node advance is unchanged (it works in
             # (B, n) node space); only the per-partition holder state and
             # its eval move to (B, W, P) uint32 words
-            upw = xp.moveaxis(bitpack.pack_words(up[:, succ], xp), -1, 1)
-            lark, maj, crepsw = pac_fn(upw, full)
-            full = xp.where(lark[:, None, :], crepsw, full)
+            with stage(xp, "lark_rank_gather"):
+                upw = xp.moveaxis(bitpack.pack_words(up[:, succ], xp), -1,
+                                  1)
+            with stage(xp, "lark_step_eval"):
+                lark, maj, crepsw = pac_fn(upw, full)
+                full = xp.where(lark[:, None, :], crepsw, full)
         else:
-            lark, maj, creps = pac_fn(up[:, succ].reshape(B * P, n),
-                                      full.reshape(B * P, n))
-            lark = lark.reshape(B, P)
-            maj = maj.reshape(B, P)
-            full = xp.where(lark[:, :, None], creps.reshape(B, P, n), full)
-        # outage events are per-partition down-transitions (the downtime
-        # engine's lgo/qgo rule): a net per-trial count delta would cancel
-        # a partition recovering in the same step another fails and
-        # undercount, starving the min_events early-stop
-        le = le + xp.sum(~dnl & ~lark, axis=1).astype(xp.int32)
-        me = me + xp.sum(~dnm & ~maj, axis=1).astype(xp.int32)
-        dnl = ~lark
-        dnm = ~maj
-        new_unl = xp.sum(dnl, axis=1).astype(xp.int32)
-        new_unm = xp.sum(dnm, axis=1).astype(xp.int32)
-        nodes_up = xp.sum(up, axis=1).astype(xp.int32)
+            with stage(xp, "lark_rank_gather"):
+                up_rows = up[:, succ].reshape(B * P, n)
+            with stage(xp, "lark_step_eval"):
+                lark, maj, creps = pac_fn(up_rows, full.reshape(B * P, n))
+                lark = lark.reshape(B, P)
+                maj = maj.reshape(B, P)
+                full = xp.where(lark[:, :, None], creps.reshape(B, P, n),
+                                full)
+        with stage(xp, "lark_protocols"):
+            # outage events are per-partition down-transitions (the
+            # downtime engine's lgo/qgo rule): a net per-trial count delta
+            # would cancel a partition recovering in the same step another
+            # fails and undercount, starving the min_events early-stop
+            le = le + xp.sum(~dnl & ~lark, axis=1).astype(xp.int32)
+            me = me + xp.sum(~dnm & ~maj, axis=1).astype(xp.int32)
+            dnl = ~lark
+            dnm = ~maj
+            new_unl = xp.sum(dnl, axis=1).astype(xp.int32)
+            new_unm = xp.sum(dnm, axis=1).astype(xp.int32)
+            nodes_up = xp.sum(up, axis=1).astype(xp.int32)
         carry = (now, up, ev_t, full, dnl, dnm, lpt, mpt, le, me,
                  rr_t, rr_idx, lane0)
         return carry, (t_clamp, new_unl, new_unm, nodes_up)
@@ -495,124 +506,145 @@ def simulate_availability_batched(
     (kernels/fused_step.py) with tile (block_t, block_p) — layout and
     fusion only, trajectories bit-identical to packed=False.
     """
-    _validate_batched_args(backend=backend, devices=devices, trials=trials,
-                           wave_width=wave_width, n=n)
-    shard = use_shard_map if use_shard_map is not None else devices > 1
-    B, P, horizon = trials, partitions, max_ticks
-    voters = voters if voters is not None else 2 * (rf - 1) + 1
-    if not 1 <= voters <= n:
-        raise ValueError("voters must be in [1, n]")
-    (xp, succ, seed_mix, geo_masks, geo_tables, dt_vec, pair_perm,
-     p_arr, dt_arr) = _engine_setup(
-        backend, n=n, partitions=P, seed=seed, p=p, downtime=downtime,
-        p_node=p_node, downtime_node=downtime_node, max_ticks=max_ticks)
-    spec = StepSpec(metric="availability", rf=rf, voters=voters, n_real=n,
-                    packed=packed)
+    call = next_call()
+    with span(backend, "lark.call", call=call, engine="availability",
+              trials=trials, partitions=partitions,
+              chunk_steps=chunk_steps):
+        _validate_batched_args(backend=backend, devices=devices,
+                               trials=trials, wave_width=wave_width, n=n)
+        shard = use_shard_map if use_shard_map is not None else devices > 1
+        B, P, horizon = trials, partitions, max_ticks
+        voters = voters if voters is not None else 2 * (rf - 1) + 1
+        if not 1 <= voters <= n:
+            raise ValueError("voters must be in [1, n]")
+        with span(backend, "lark.setup", call=call):
+            (xp, succ, seed_mix, geo_masks, geo_tables, dt_vec, pair_perm,
+             p_arr, dt_arr) = _engine_setup(
+                backend, n=n, partitions=P, seed=seed, p=p,
+                downtime=downtime, p_node=p_node,
+                downtime_node=downtime_node, max_ticks=max_ticks)
+            spec = StepSpec(metric="availability", rf=rf, voters=voters,
+                            n_real=n, packed=packed)
 
-    def pac_fn(u, f):
-        o = step_eval(spec, u, f, backend=backend, block_p=pac_block_p,
-                      block_t=block_t)
-        return o.lark, o.maj, o.creps
+            def pac_fn(u, f):
+                o = step_eval(spec, u, f, backend=backend,
+                              block_p=pac_block_p, block_t=block_t)
+                return o.lark, o.maj, o.creps
 
-    step = _make_step(xp, pac_fn, succ, n=n, P=P, horizon=horizon,
-                      dt_vec=dt_vec, geo_masks=geo_masks,
-                      geo_tables=geo_tables, seed_mix=seed_mix,
-                      pair_fail_prob=pair_fail_prob, pair_perm=pair_perm,
-                      restart_period=restart_period, wave_width=wave_width,
-                      packed=packed)
+            step = _make_step(xp, pac_fn, succ, n=n, P=P, horizon=horizon,
+                              dt_vec=dt_vec, geo_masks=geo_masks,
+                              geo_tables=geo_tables, seed_mix=seed_mix,
+                              pair_fail_prob=pair_fail_prob,
+                              pair_perm=pair_perm,
+                              restart_period=restart_period,
+                              wave_width=wave_width, packed=packed)
 
-    # initial state: everyone up, roster replicas full
-    lane0, up0, ev0, rr_t0 = _initial_node_state(
-        xp, B=B, n=n, seed_mix=seed_mix, geo_masks=geo_masks,
-        geo_tables=geo_tables, restart_period=restart_period,
-        horizon=horizon)
-    full0, (lark0, maj0, _creps0) = _initial_full_state(
-        xp, backend, pac_fn, up0, succ, B=B, P=P, n=n, rf=rf,
-        packed=packed)
-    zi = xp.zeros((B,), dtype=xp.int32)
-    zf = xp.zeros((B,), dtype=xp.float32)
-    carry = (zi, up0, ev0, full0,
-             ~lark0.reshape(B, P),                 # dnl (per-partition)
-             ~maj0.reshape(B, P),                  # dnm
-             zf, zf, zi, zi, rr_t0, zi, lane0)
+            # initial state: everyone up, roster replicas full
+            lane0, up0, ev0, rr_t0 = _initial_node_state(
+                xp, B=B, n=n, seed_mix=seed_mix, geo_masks=geo_masks,
+                geo_tables=geo_tables, restart_period=restart_period,
+                horizon=horizon)
+            full0, (lark0, maj0, _creps0) = _initial_full_state(
+                xp, backend, pac_fn, up0, succ, B=B, P=P, n=n, rf=rf,
+                packed=packed)
+            zi = xp.zeros((B,), dtype=xp.int32)
+            zf = xp.zeros((B,), dtype=xp.float32)
+            carry = (zi, up0, ev0, full0,
+                     ~lark0.reshape(B, P),         # dnl (per-partition)
+                     ~maj0.reshape(B, P),          # dnm
+                     zf, zf, zi, zi, rr_t0, zi, lane0)
+            if max_steps is None:
+                max_steps = _default_max_steps(
+                    p_arr, dt_arr, n=n, horizon=horizon,
+                    restart_period=restart_period)
 
-    if backend != "numpy":
-        import jax.numpy as jnp
-        run_chunk = _make_chunk_runner(step, carry, chunk_steps=chunk_steps,
-                                       devices=devices, shard=shard,
-                                       n_outputs=4)
-
-    if max_steps is None:
-        max_steps = _default_max_steps(p_arr, dt_arr, n=n, horizon=horizon,
-                                       restart_period=restart_period)
-
-    lpt_tot = np.zeros(B)
-    mpt_tot = np.zeros(B)
-    le_tot = me_tot = 0
-    traj = [] if trajectory else None
-    stopped = False
-    s0 = 1
-    while s0 < max_steps:
-        if backend == "numpy":
-            carry, ys = _run_chunk_numpy(step, carry, s0, chunk_steps)
-        else:
-            carry, ys = run_chunk(carry, jnp.int32(s0))
-        s0 += chunk_steps
-        if trajectory:
-            traj.append(tuple(np.asarray(c) for c in ys))
-        # drain per-chunk accumulators into float64/int totals
-        now = np.asarray(carry[0], dtype=np.int64)
-        lpt_tot += np.asarray(carry[6], dtype=np.float64)
-        mpt_tot += np.asarray(carry[7], dtype=np.float64)
-        le_tot += int(np.asarray(carry[8]).sum())
-        me_tot += int(np.asarray(carry[9]).sum())
-        carry = carry[:6] + (zf, zf, zi, zi) + carry[10:]
-        if (now >= horizon).all():
-            break
-        # pooled CI early stop, mirroring the event engine's rule.  This is
-        # deliberately the NOMINAL binomial width — the same stopping
-        # semantics (and therefore comparable tick counts / wall-clock) as
-        # the scalar engine — while the *reported* ci_lark/ci_maj use the
-        # honest across-trial spread, which is typically wider.
-        if now.mean() >= min_ticks and le_tot >= min_events \
-                and me_tot >= min_events:
-            pt = float(P) * float(now.sum())
-            u_l, u_m = lpt_tot.sum() / pt, mpt_tot.sum() / pt
-            hw_l = 1.96 * math.sqrt(max(u_l * (1 - u_l), 1e-30) / pt)
-            hw_m = 1.96 * math.sqrt(max(u_m * (1 - u_m), 1e-30) / pt)
-            if hw_l <= max(eps_abs, eps_rel * u_l) and \
-                    hw_m <= max(eps_abs, eps_rel * u_m):
-                stopped = True
+        lpt_tot = np.zeros(B)
+        mpt_tot = np.zeros(B)
+        le_tot = me_tot = 0
+        traj = [] if trajectory else None
+        stopped = False
+        if backend != "numpy":
+            import jax.numpy as jnp
+        s0 = 1
+        chunk = 0
+        while s0 < max_steps:
+            if backend == "numpy":
+                carry, ys = _run_chunk_numpy(step, carry, s0, chunk_steps)
+            elif chunk == 0:
+                with span(backend, "lark.chunk_program", call=call):
+                    run_chunk = _make_chunk_runner(
+                        step, carry, chunk_steps=chunk_steps,
+                        devices=devices, shard=shard, n_outputs=4)
+                    carry, ys = run_chunk(carry, jnp.int32(s0))
+            else:
+                with span(backend, "lark.dispatch", call=call, chunk=chunk,
+                          s0=s0):
+                    carry, ys = run_chunk(carry, jnp.int32(s0))
+            s0 += chunk_steps
+            with span(backend, "lark.drain", call=call,
+                      chunk=chunk) as drain:
+                if trajectory:
+                    traj.append(tuple(np.asarray(c) for c in ys))
+                # drain per-chunk accumulators into float64/int totals
+                now = np.asarray(carry[0], dtype=np.int64)
+                lpt_tot += np.asarray(carry[6], dtype=np.float64)
+                mpt_tot += np.asarray(carry[7], dtype=np.float64)
+                le_tot += int(np.asarray(carry[8]).sum())
+                me_tot += int(np.asarray(carry[9]).sum())
+                carry = carry[:6] + (zf, zf, zi, zi) + carry[10:]
+                annotate(drain, ticks=float(now.mean()))
+            with span(backend, "lark.stop_test", call=call,
+                      chunk=chunk) as stop_test:
+                done = bool((now >= horizon).all())
+                # pooled CI early stop, mirroring the event engine's rule.
+                # This is deliberately the NOMINAL binomial width — the
+                # same stopping semantics (and therefore comparable tick
+                # counts / wall-clock) as the scalar engine — while the
+                # *reported* ci_lark/ci_maj use the honest across-trial
+                # spread, which is typically wider.
+                if not done and now.mean() >= min_ticks \
+                        and le_tot >= min_events and me_tot >= min_events:
+                    pt = float(P) * float(now.sum())
+                    u_l, u_m = lpt_tot.sum() / pt, mpt_tot.sum() / pt
+                    hw_l = 1.96 * math.sqrt(max(u_l * (1 - u_l), 1e-30) / pt)
+                    hw_m = 1.96 * math.sqrt(max(u_m * (1 - u_m), 1e-30) / pt)
+                    stopped = done = bool(
+                        hw_l <= max(eps_abs, eps_rel * u_l)
+                        and hw_m <= max(eps_abs, eps_rel * u_m))
+                annotate(stop_test, stopped=done)
+            if done:
                 break
+            chunk += 1
 
-    now = np.maximum(np.asarray(carry[0], dtype=np.int64), 1)
-    pt_b = P * now.astype(np.float64)
-    pt = float(pt_b.sum())
-    u_l = float(lpt_tot.sum()) / pt
-    u_m = float(mpt_tot.sum()) / pt
-    u_l_trials = lpt_tot / pt_b
-    u_m_trials = mpt_tot / pt_b
-    # honest CI from the spread of independent trials (captures the
-    # node-failure correlation across partitions that the binomial width
-    # misses), floored by the pooled binomial width for tiny batches
-    hw_l = hw_m = 0.0
-    if B >= 3:
-        t = t975(B - 1) / math.sqrt(B)
-        hw_l = t * float(u_l_trials.std(ddof=1))
-        hw_m = t * float(u_m_trials.std(ddof=1))
-    traj_out = None
-    if trajectory:
-        cols = [np.concatenate([c[i] for c in traj]) for i in range(4)]
-        traj_out = {"times": cols[0], "unavail_lark": cols[1],
-                    "unavail_maj": cols[2], "nodes_up": cols[3]}
-    return BatchedAvailabilityResult(
-        p=p, rf=rf, n=n, partitions=P, trials=B, backend=backend,
-        ticks=int(now.mean()), u_lark=u_l, u_maj=u_m,
-        lark_events=le_tot, maj_events=me_tot,
-        ci_lark=max(hw_l,
-                    1.96 * math.sqrt(max(u_l * (1 - u_l), 1e-30) / pt)),
-        ci_maj=max(hw_m,
-                   1.96 * math.sqrt(max(u_m * (1 - u_m), 1e-30) / pt)),
-        stopped_early=stopped, devices=devices,
-        u_lark_trials=u_l_trials, u_maj_trials=u_m_trials,
-        trajectory=traj_out)
+        now = np.maximum(np.asarray(carry[0], dtype=np.int64), 1)
+        pt_b = P * now.astype(np.float64)
+        pt = float(pt_b.sum())
+        u_l = float(lpt_tot.sum()) / pt
+        u_m = float(mpt_tot.sum()) / pt
+        u_l_trials = lpt_tot / pt_b
+        u_m_trials = mpt_tot / pt_b
+        # honest CI from the spread of independent trials (captures the
+        # node-failure correlation across partitions that the binomial
+        # width misses), floored by the pooled binomial width for tiny
+        # batches
+        hw_l = hw_m = 0.0
+        if B >= 3:
+            t = t975(B - 1) / math.sqrt(B)
+            hw_l = t * float(u_l_trials.std(ddof=1))
+            hw_m = t * float(u_m_trials.std(ddof=1))
+        traj_out = None
+        if trajectory:
+            cols = [np.concatenate([c[i] for c in traj]) for i in range(4)]
+            traj_out = {"times": cols[0], "unavail_lark": cols[1],
+                        "unavail_maj": cols[2], "nodes_up": cols[3]}
+        return BatchedAvailabilityResult(
+            p=p, rf=rf, n=n, partitions=P, trials=B, backend=backend,
+            ticks=int(now.mean()), u_lark=u_l, u_maj=u_m,
+            lark_events=le_tot, maj_events=me_tot,
+            ci_lark=max(hw_l,
+                        1.96 * math.sqrt(max(u_l * (1 - u_l), 1e-30) / pt)),
+            ci_maj=max(hw_m,
+                       1.96 * math.sqrt(max(u_m * (1 - u_m), 1e-30) / pt)),
+            stopped_early=stopped, devices=devices,
+            u_lark_trials=u_l_trials, u_maj_trials=u_m_trials,
+            trajectory=traj_out)

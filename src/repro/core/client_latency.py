@@ -205,10 +205,10 @@ def key_bucket_shares(key_zipf: float, *,
 
 @dataclass(frozen=True)
 class _LatencyPlan:
-    """Host-precomputed workload tables handed to the downtime driver
-    (simulate_downtime_batched's `_lat_plan`): per-bucket key counts,
-    per-partition float32 write rates, and the decay power tables —
-    everything the in-scan latency update consumes."""
+    """Host-precomputed workload tables the downtime driver builds in its
+    set-up (simulate_downtime_batched's `_lat_plan_of`): per-bucket key
+    counts, per-partition float32 write rates, and the decay power
+    tables — everything the in-scan latency update consumes."""
     nbins: int
     slo_ticks: int
     kf: np.ndarray           # (NB,) float32 keys per bucket (K * f_b)
@@ -371,10 +371,9 @@ def simulate_client_latency(
             key_zipf=key_zipf, read_frac=read_frac,
             requests_per_tick=requests_per_tick, slo_ticks=slo_ticks,
             write_skew=write_skew, slo_curve_bins=slo_curve_bins)
-    plan = make_latency_plan(seed, partitions, params, max_ticks)
     res = simulate_downtime_batched(
         partitions=partitions, seed=seed, max_ticks=max_ticks,
-        params=params, _lat_plan=plan, **kwargs)
+        params=params, _lat_plan_of=make_latency_plan, **kwargs)
 
     raw = res.latency_raw
     now = raw["now"].astype(np.float64)                       # (B,)

@@ -102,6 +102,7 @@ from ..kernels import bitpack
 from ..kernels.ops import (StepSpec, _rebuild_node_counts_impl,
                            client_latency_step, step_eval)
 from .availability import t975
+from .stages import annotate, next_call, span, stage
 from .availability_batched import (_default_max_steps, _engine_setup,
                                    _initial_full_state, _initial_node_state,
                                    _make_chunk_runner, _make_node_advance,
@@ -548,15 +549,17 @@ def _make_step(xp, dt_fn, advance, succ, *, n: int, P: int, rf: int,
         charge identically."""
         if lat_fn is None:
             return lat
-        return lat_fn(lat, dt_i, ~ldn, qmaj_prev, rem)
+        with stage(xp, "lark_latency"):
+            return lat_fn(lat, dt_i, ~ldn, qmaj_prev, rem)
 
     def lat_dirty_reset(lat, pen):
         """A leader change onto a stale leader makes every key of the
         partition dirty: its next touch pays the dup-res round."""
         if lat_fn is None or pen is None:
             return lat
-        return (xp.where(pen[:, :, None], xp.float32(1.0), lat[0]),) \
-            + lat[1:]
+        with stage(xp, "lark_latency"):
+            return (xp.where(pen[:, :, None], xp.float32(1.0), lat[0]),) \
+                + lat[1:]
 
     # -- shared protocol blocks.  Both rebuild models run these verbatim
     # (the models differ only in how the replica set and the rebuild
@@ -754,62 +757,70 @@ def _make_step(xp, dt_fn, advance, succ, *, n: int, P: int, rf: int,
         B = up.shape[0]               # local trials (a shard of the batch)
         t_clamp, dt, active, up, ev_t, rr_t, rr_idx = advance(
             now, up, ev_t, rr_t, rr_idx, lane0, s)
-        dt_i = t_clamp - now                                  # (B,) int32
-        lpt, qpt, qreb, qdn, qhist, qmaj_prev, rem0 = interval_pause(
-            now, dt, dt_i, ldn, qrep, qreb, qdn, qt0, lpt, qpt, qhist)
-        if hermes:
-            hstate = hermes_interval(now, dt, dt_i, ldn, hstate)
+        with stage(xp, "lark_protocols"):
+            dt_i = t_clamp - now                              # (B,) int32
+            lpt, qpt, qreb, qdn, qhist, qmaj_prev, rem0 = interval_pause(
+                now, dt, dt_i, ldn, qrep, qreb, qdn, qt0, lpt, qpt, qhist)
+            if hermes:
+                hstate = hermes_interval(now, dt, dt_i, ldn, hstate)
         lat = lat_interval(lat, dt_i, ldn, qmaj_prev, rem0)
         now = t_clamp
 
         # -- re-evaluate both protocols on the post-event cluster state
-        up_succ = up[:, succ]                                 # (B, P, n)
-        rep_new = up_succ[:, :, :rf]                          # replica lanes
+        with stage(xp, "lark_rank_gather"):
+            up_succ = up[:, succ]                             # (B, P, n)
+            rep_new = up_succ[:, :, :rf]                      # replica lanes
+            if packed:
+                up_in = xp.moveaxis(bitpack.pack_words(up_succ, xp), -1, 1)
+            else:
+                up_in = up_succ.reshape(B * P, n)
         repm = None
-        if packed:
-            upw = xp.moveaxis(bitpack.pack_words(up_succ, xp), -1, 1)
-            out_t = dt_fn(upw, full)
-            lark, qmaj, ldr, lfull = out_t[:4]
-            crepsw = out_t[-1]
-            if hermes:
-                repm = out_t[5]
-            full = xp.where(lark[:, None, :], crepsw, full)
-        else:
-            out_t = dt_fn(
-                up_succ.reshape(B * P, n), full.reshape(B * P, n))
-            lark = out_t[0].reshape(B, P)
-            qmaj = out_t[1].reshape(B, P)
-            ldr = out_t[2].reshape(B, P)
-            lfull = out_t[3].reshape(B, P)
-            if hermes:
-                repm = out_t[5].reshape(B, P)
-            full = xp.where(lark[:, :, None],
-                            out_t[-1].reshape(B, P, n), full)
+        with stage(xp, "lark_step_eval"):
+            if packed:
+                out_t = dt_fn(up_in, full)
+                lark, qmaj, ldr, lfull = out_t[:4]
+                crepsw = out_t[-1]
+                if hermes:
+                    repm = out_t[5]
+                full = xp.where(lark[:, None, :], crepsw, full)
+            else:
+                out_t = dt_fn(up_in, full.reshape(B * P, n))
+                lark = out_t[0].reshape(B, P)
+                qmaj = out_t[1].reshape(B, P)
+                ldr = out_t[2].reshape(B, P)
+                lfull = out_t[3].reshape(B, P)
+                if hermes:
+                    repm = out_t[5].reshape(B, P)
+                full = xp.where(lark[:, :, None],
+                                out_t[-1].reshape(B, P, n), full)
 
-        ldn, lt0, leader, lpt, lev, lhist, pen = lark_transitions(
-            t_clamp, lark, ldr, lfull, ldn, lt0, leader, lpt, lev, lhist)
+        with stage(xp, "lark_protocols"):
+            ldn, lt0, leader, lpt, lev, lhist, pen = lark_transitions(
+                t_clamp, lark, ldr, lfull, ldn, lt0, leader, lpt, lev,
+                lhist)
         lat = lat_dirty_reset(lat, pen)
 
-        # -- any replica loss (a replica-set lane going up -> down, even
-        # if masked by a simultaneous recovery of another lane)
-        # (re)starts the constant rebuild countdown
-        if rebuild_steps > 0:
-            loss = xp.any(qrep & ~rep_new, axis=2)
-            qreb = xp.where(loss, xp.int32(rebuild_steps), qreb)
-        qdn, qt0, qev, qhist = quorum_transitions(
-            t_clamp, qmaj, qreb, qdn, qt0, qev, qhist)
-        qrep = rep_new
-        if hermes:
-            hstate = hermes_post(t_clamp, lark, repm, hstate)
+        with stage(xp, "lark_protocols"):
+            # -- any replica loss (a replica-set lane going up -> down,
+            # even if masked by a simultaneous recovery of another lane)
+            # (re)starts the constant rebuild countdown
+            if rebuild_steps > 0:
+                loss = xp.any(qrep & ~rep_new, axis=2)
+                qreb = xp.where(loss, xp.int32(rebuild_steps), qreb)
+            qdn, qt0, qev, qhist = quorum_transitions(
+                t_clamp, qmaj, qreb, qdn, qt0, qev, qhist)
+            qrep = rep_new
+            if hermes:
+                hstate = hermes_post(t_clamp, lark, repm, hstate)
 
-        carry = (now, up, ev_t, full, rr_t, rr_idx, lane0, ldn, lt0,
-                 qrep, qreb, qdn, qt0, leader, lpt, qpt, lev, qev,
-                 lhist, qhist) + (hstate if hermes else ()) + lat
-        out = (t_clamp, xp.sum(ldn, axis=1).astype(xp.int32),
-               xp.sum(qdn, axis=1).astype(xp.int32),
-               xp.sum(up, axis=1).astype(xp.int32))
-        if hermes:
-            out = out + (xp.sum(hstate[0], axis=1).astype(xp.int32),)
+            carry = (now, up, ev_t, full, rr_t, rr_idx, lane0, ldn, lt0,
+                     qrep, qreb, qdn, qt0, leader, lpt, qpt, lev, qev,
+                     lhist, qhist) + (hstate if hermes else ()) + lat
+            out = (t_clamp, xp.sum(ldn, axis=1).astype(xp.int32),
+                   xp.sum(qdn, axis=1).astype(xp.int32),
+                   xp.sum(up, axis=1).astype(xp.int32))
+            if hermes:
+                out = out + (xp.sum(hstate[0], axis=1).astype(xp.int32),)
         return carry, out
 
     def step_fixed_bw(carry, s):
@@ -842,56 +853,66 @@ def _make_step(xp, dt_fn, advance, succ, *, n: int, P: int, rf: int,
         B = up.shape[0]               # local trials (a shard of the batch)
         t_clamp, dt, active, up, ev_t, rr_t, rr_idx = advance(
             now, up, ev_t, rr_t, rr_idx, lane0, s)
-        dt_i = t_clamp - now                                  # (B,) int32
 
         # -- post-event cluster state + the in-flight node counts from
         # the carried interval-start recruit/qreb (the same reduction as
         # the reconfig steps; one fused call when packed)
-        up_succ = up[:, succ]                                 # (B, P, n)
-        rep_new = up_succ[:, :, :rf]                          # replica lanes
-        inflight = (qreb > 0) & (recruit < n)
+        with stage(xp, "lark_rank_gather"):
+            up_succ = up[:, succ]                             # (B, P, n)
+            rep_new = up_succ[:, :, :rf]                      # replica lanes
+            if packed:
+                up_in = xp.moveaxis(bitpack.pack_words(up_succ, xp), -1, 1)
+            else:
+                up_in = up_succ.reshape(B * P, n)
+        with stage(xp, "lark_node_counts"):
+            inflight = (qreb > 0) & (recruit < n)
         repm = None
-        if packed:
-            upw = xp.moveaxis(bitpack.pack_words(up_succ, xp), -1, 1)
-            out_t = dt_fn(upw, full, None, recruit, inflight)
-            lark, qmaj, ldr, lfull = out_t[:4]
-            counts = out_t[-1]
-            crepsw = out_t[-2]
-            if hermes:
-                repm = out_t[5]
-        else:
-            out_t = dt_fn(up_succ.reshape(B * P, n),
-                          full.reshape(B * P, n), None, recruit, inflight)
-            lark = out_t[0].reshape(B, P)
-            qmaj = out_t[1].reshape(B, P)
-            ldr = out_t[2].reshape(B, P)
-            lfull = out_t[3].reshape(B, P)
-            counts = out_t[-1]
-            if hermes:
-                repm = out_t[5].reshape(B, P)
-        kk = xp.take_along_axis(counts,
-                                xp.clip(recruit, 0, n - 1), axis=1)
-        # sentinel-recruit partitions must not inherit node n-1's
-        # in-flight count from the clipped gather (see step_reconfig)
-        kk = xp.where(recruit < n, xp.maximum(kk, 1), 1)
-        rate = xp.minimum(xp.int32(_REB_SCALE),
-                          xp.int32(bandwidth_fp) // kk)
+        with stage(xp, "lark_step_eval"):
+            if packed:
+                out_t = dt_fn(up_in, full, None, recruit, inflight)
+                lark, qmaj, ldr, lfull = out_t[:4]
+                crepsw = out_t[-2]
+                if hermes:
+                    repm = out_t[5]
+            else:
+                out_t = dt_fn(up_in, full.reshape(B * P, n), None, recruit,
+                              inflight)
+                lark = out_t[0].reshape(B, P)
+                qmaj = out_t[1].reshape(B, P)
+                ldr = out_t[2].reshape(B, P)
+                lfull = out_t[3].reshape(B, P)
+                if hermes:
+                    repm = out_t[5].reshape(B, P)
+        counts = out_t[-1]
+        with stage(xp, "lark_node_counts"):
+            kk = xp.take_along_axis(counts,
+                                    xp.clip(recruit, 0, n - 1), axis=1)
+            # sentinel-recruit partitions must not inherit node n-1's
+            # in-flight count from the clipped gather (see step_reconfig)
+            kk = xp.where(recruit < n, xp.maximum(kk, 1), 1)
+            rate = xp.minimum(xp.int32(_REB_SCALE),
+                              xp.int32(bandwidth_fp) // kk)
 
-        lpt, qpt, qreb, qdn, qhist, qmaj_prev, rem0 = interval_pause(
-            now, dt, dt_i, ldn, qrep, qreb, qdn, qt0, lpt, qpt, qhist,
-            rate=rate)
-        if hermes:
-            hstate = hermes_interval(now, dt, dt_i, ldn, hstate)
+        with stage(xp, "lark_protocols"):
+            dt_i = t_clamp - now                              # (B,) int32
+            lpt, qpt, qreb, qdn, qhist, qmaj_prev, rem0 = interval_pause(
+                now, dt, dt_i, ldn, qrep, qreb, qdn, qt0, lpt, qpt, qhist,
+                rate=rate)
+            if hermes:
+                hstate = hermes_interval(now, dt, dt_i, ldn, hstate)
         lat = lat_interval(lat, dt_i, ldn, qmaj_prev, rem0)
         now = t_clamp
 
-        if packed:
-            full = xp.where(lark[:, None, :], crepsw, full)
-        else:
-            full = xp.where(lark[:, :, None],
-                            out_t[-2].reshape(B, P, n), full)
-        ldn, lt0, leader, lpt, lev, lhist, pen = lark_transitions(
-            t_clamp, lark, ldr, lfull, ldn, lt0, leader, lpt, lev, lhist)
+        with stage(xp, "lark_step_eval"):
+            if packed:
+                full = xp.where(lark[:, None, :], crepsw, full)
+            else:
+                full = xp.where(lark[:, :, None],
+                                out_t[-2].reshape(B, P, n), full)
+        with stage(xp, "lark_protocols"):
+            ldn, lt0, leader, lpt, lev, lhist, pen = lark_transitions(
+                t_clamp, lark, ldr, lfull, ldn, lt0, leader, lpt, lev,
+                lhist)
         lat = lat_dirty_reset(lat, pen)
 
         # -- a replica loss (re)starts the constant countdown, now in
@@ -900,29 +921,34 @@ def _make_step(xp, dt_fn, advance, succ, *, n: int, P: int, rf: int,
         # (simultaneous losses replay onto the first — one log stream
         # per partition, like the reconfig model's single recruit)
         if rebuild_fp is not None and rebuild_fp > 0:
-            lost = qrep & ~rep_new                            # (B, P, rf)
-            loss = xp.any(lost, axis=2)
-            qreb = xp.where(loss, xp.int32(rebuild_fp), qreb)
-            rank = xp.min(xp.where(lost,
-                                   xp.arange(rf, dtype=xp.int32)
-                                   [None, None, :], xp.int32(rf)), axis=2)
-            node = succ[xp.arange(P, dtype=xp.int32)[None, :],
-                        xp.clip(rank, 0, rf - 1)]
-            recruit = xp.where(loss, node, recruit)
-        qdn, qt0, qev, qhist = quorum_transitions(
-            t_clamp, qmaj, qreb, qdn, qt0, qev, qhist)
-        qrep = rep_new
-        if hermes:
-            hstate = hermes_post(t_clamp, lark, repm, hstate)
+            with stage(xp, "lark_protocols"):
+                lost = qrep & ~rep_new                        # (B, P, rf)
+                loss = xp.any(lost, axis=2)
+                qreb = xp.where(loss, xp.int32(rebuild_fp), qreb)
+            with stage(xp, "lark_node_counts"):
+                rank = xp.min(xp.where(lost,
+                                       xp.arange(rf, dtype=xp.int32)
+                                       [None, None, :], xp.int32(rf)),
+                              axis=2)
+                node = succ[xp.arange(P, dtype=xp.int32)[None, :],
+                            xp.clip(rank, 0, rf - 1)]
+                recruit = xp.where(loss, node, recruit)
+        with stage(xp, "lark_protocols"):
+            qdn, qt0, qev, qhist = quorum_transitions(
+                t_clamp, qmaj, qreb, qdn, qt0, qev, qhist)
+            qrep = rep_new
+            if hermes:
+                hstate = hermes_post(t_clamp, lark, repm, hstate)
 
-        carry = (now, up, ev_t, full, rr_t, rr_idx, lane0, ldn, lt0,
-                 qrep, qreb, qdn, qt0, leader, lpt, qpt, lev, qev,
-                 lhist, qhist, recruit) + (hstate if hermes else ()) + lat
-        out = (t_clamp, xp.sum(ldn, axis=1).astype(xp.int32),
-               xp.sum(qdn, axis=1).astype(xp.int32),
-               xp.sum(up, axis=1).astype(xp.int32))
-        if hermes:
-            out = out + (xp.sum(hstate[0], axis=1).astype(xp.int32),)
+            carry = (now, up, ev_t, full, rr_t, rr_idx, lane0, ldn, lt0,
+                     qrep, qreb, qdn, qt0, leader, lpt, qpt, lev, qev,
+                     lhist, qhist, recruit) + (hstate if hermes else ()) \
+                + lat
+            out = (t_clamp, xp.sum(ldn, axis=1).astype(xp.int32),
+                   xp.sum(qdn, axis=1).astype(xp.int32),
+                   xp.sum(up, axis=1).astype(xp.int32))
+            if hermes:
+                out = out + (xp.sum(hstate[0], axis=1).astype(xp.int32),)
         return carry, out
 
     lanes_n = xp.arange(n, dtype=xp.int32)
@@ -991,7 +1017,6 @@ def _make_step(xp, dt_fn, advance, succ, *, n: int, P: int, rf: int,
         B = up.shape[0]               # local trials (a shard of the batch)
         t_clamp, dt, active, up, ev_t, rr_t, rr_idx = advance(
             now, up, ev_t, rr_t, rr_idx, lane0, s)
-        dt_i = t_clamp - now                                  # (B,) int32
         # -- per-node bandwidth contention over this interval: in-flight
         # catch-ups ingesting on the same recruit node split its
         # bandwidth evenly (the in-flight set only changes at events, so
@@ -1003,87 +1028,101 @@ def _make_step(xp, dt_fn, advance, succ, *, n: int, P: int, rf: int,
         if bandwidth_fp is None:
             rate = xp.full((B, P), _REB_SCALE, dtype=xp.int32)
         else:
-            inflight = (qreb > 0) & (recruit < n)
-            counts = cnt_fn(recruit, inflight)                # (B, n)
-            k = xp.take_along_axis(counts,
-                                   xp.clip(recruit, 0, n - 1), axis=1)
-            # sentinel-recruit partitions must not inherit node n-1's
-            # in-flight count from the clipped gather: no known ingest
-            # node means no contention
-            k = xp.where(recruit < n, xp.maximum(k, 1), 1)
-            rate = xp.minimum(xp.int32(_REB_SCALE),
-                              xp.int32(bandwidth_fp) // k)
-        lpt, qpt, qreb, qdn, qhist, qmaj_prev, rem0 = interval_pause(
-            now, dt, dt_i, ldn, qrep, qreb, qdn, qt0, lpt, qpt, qhist,
-            rate=rate)
-        if hermes:
-            hstate = hermes_interval(now, dt, dt_i, ldn, hstate)
-        if spinnaker:
-            sstate = spinnaker_interval(now, dt, dt_i, qmaj_prev, rem0,
-                                        sstate)
+            with stage(xp, "lark_node_counts"):
+                inflight = (qreb > 0) & (recruit < n)
+                counts = cnt_fn(recruit, inflight)            # (B, n)
+                k = xp.take_along_axis(counts,
+                                       xp.clip(recruit, 0, n - 1), axis=1)
+                # sentinel-recruit partitions must not inherit node n-1's
+                # in-flight count from the clipped gather: no known
+                # ingest node means no contention
+                k = xp.where(recruit < n, xp.maximum(k, 1), 1)
+                rate = xp.minimum(xp.int32(_REB_SCALE),
+                                  xp.int32(bandwidth_fp) // k)
+        with stage(xp, "lark_protocols"):
+            dt_i = t_clamp - now                              # (B,) int32
+            lpt, qpt, qreb, qdn, qhist, qmaj_prev, rem0 = interval_pause(
+                now, dt, dt_i, ldn, qrep, qreb, qdn, qt0, lpt, qpt, qhist,
+                rate=rate)
+            if hermes:
+                hstate = hermes_interval(now, dt, dt_i, ldn, hstate)
+            if spinnaker:
+                sstate = spinnaker_interval(now, dt, dt_i, qmaj_prev, rem0,
+                                            sstate)
         lat = lat_interval(lat, dt_i, ldn, qmaj_prev, rem0)
         now = t_clamp
 
         # -- post-event cluster state; fresh losses are roster members
         # that were up at interval start and are down now
-        up_succ = up[:, succ]                                 # (B, P, n)
-        rup = xp.take_along_axis(up_succ, roster, axis=2)     # (B, P, rf)
-        loss_any = xp.any(qrep & ~rup, axis=2)
+        with stage(xp, "lark_rank_gather"):
+            up_succ = up[:, succ]                             # (B, P, n)
+        with stage(xp, "lark_roster"):
+            rup = xp.take_along_axis(up_succ, roster, axis=2)  # (B, P, rf)
+            loss_any = xp.any(qrep & ~rup, axis=2)
 
-        # -- recruit: every down roster member is replaced by the first
-        # up node in succession order not already in the roster
-        roster, new_rank, took = recruit_roster(up_succ, rup, roster)
+            # -- recruit: every down roster member is replaced by the
+            # first up node in succession order not already in the roster
+            roster, new_rank, took = recruit_roster(up_succ, rup, roster)
 
         # -- each fresh loss (re)starts the data-sized catch-up countdown
-        qreb = xp.where(loss_any, rebuild_ticks[None, :], qreb)
+        with stage(xp, "lark_protocols"):
+            qreb = xp.where(loss_any, rebuild_ticks[None, :], qreb)
         # -- the ingesting node is the most recently recruited member
         # (ranks are per-partition succession indices; bandwidth is per
         # physical node, so map through the succession matrix).  A loss
         # with no candidate leaves the seat — and the ingest node —
         # unknown until late recruitment fills it.
-        new_node = succ[xp.arange(P, dtype=xp.int32)[None, :],
-                        xp.clip(new_rank, 0, n - 1)]
-        recruit = xp.where(took, new_node,
-                           xp.where(loss_any, xp.int32(n), recruit))
+        with stage(xp, "lark_node_counts"):
+            new_node = succ[xp.arange(P, dtype=xp.int32)[None, :],
+                            xp.clip(new_rank, 0, n - 1)]
+            recruit = xp.where(took, new_node,
+                               xp.where(loss_any, xp.int32(n), recruit))
 
         # -- roster-aware per-step evaluation on the reconfigured roster
-        out_t = dt_fn(
-            up_succ.reshape(B * P, n), full.reshape(B * P, n),
-            roster.reshape(B * P, rf))
-        lark = out_t[0].reshape(B, P)
-        qmaj = out_t[1].reshape(B, P)
-        ldr = out_t[2].reshape(B, P)
-        lfull = out_t[3].reshape(B, P)
-        repm = out_t[5].reshape(B, P) if hermes else None
-        rlead = out_t[5 + int(hermes)].reshape(B, P) if spinnaker \
-            else None
-        full = xp.where(lark[:, :, None], out_t[-1].reshape(B, P, n),
-                        full)
+        with stage(xp, "lark_rank_gather"):
+            up_in = up_succ.reshape(B * P, n)
+        with stage(xp, "lark_step_eval"):
+            out_t = dt_fn(up_in, full.reshape(B * P, n),
+                          roster.reshape(B * P, rf))
+            lark = out_t[0].reshape(B, P)
+            qmaj = out_t[1].reshape(B, P)
+            ldr = out_t[2].reshape(B, P)
+            lfull = out_t[3].reshape(B, P)
+            repm = out_t[5].reshape(B, P) if hermes else None
+            rlead = out_t[5 + int(hermes)].reshape(B, P) if spinnaker \
+                else None
+            full = xp.where(lark[:, :, None], out_t[-1].reshape(B, P, n),
+                            full)
 
-        ldn, lt0, leader, lpt, lev, lhist, pen = lark_transitions(
-            t_clamp, lark, ldr, lfull, ldn, lt0, leader, lpt, lev, lhist)
+        with stage(xp, "lark_protocols"):
+            ldn, lt0, leader, lpt, lev, lhist, pen = lark_transitions(
+                t_clamp, lark, ldr, lfull, ldn, lt0, leader, lpt, lev,
+                lhist)
         lat = lat_dirty_reset(lat, pen)
-        qdn, qt0, qev, qhist = quorum_transitions(
-            t_clamp, qmaj, qreb, qdn, qt0, qev, qhist)
-        qrep = xp.take_along_axis(up_succ, roster, axis=2)
-        if hermes:
-            hstate = hermes_post(t_clamp, lark, repm, hstate)
-        if spinnaker:
-            sstate = spinnaker_post(t_clamp, qmaj, qreb, qrep, roster,
-                                    rlead, sstate)
+        with stage(xp, "lark_protocols"):
+            qdn, qt0, qev, qhist = quorum_transitions(
+                t_clamp, qmaj, qreb, qdn, qt0, qev, qhist)
+        with stage(xp, "lark_roster"):
+            qrep = xp.take_along_axis(up_succ, roster, axis=2)
+        with stage(xp, "lark_protocols"):
+            if hermes:
+                hstate = hermes_post(t_clamp, lark, repm, hstate)
+            if spinnaker:
+                sstate = spinnaker_post(t_clamp, qmaj, qreb, qrep, roster,
+                                        rlead, sstate)
 
-        carry = (now, up, ev_t, full, rr_t, rr_idx, lane0, ldn, lt0,
-                 qrep, qreb, qdn, qt0, leader, lpt, qpt, lev, qev,
-                 lhist, qhist, roster, recruit) \
-            + (hstate if hermes else ()) \
-            + (sstate if spinnaker else ()) + lat
-        out = (t_clamp, xp.sum(ldn, axis=1).astype(xp.int32),
-               xp.sum(qdn, axis=1).astype(xp.int32),
-               xp.sum(up, axis=1).astype(xp.int32))
-        if hermes:
-            out = out + (xp.sum(hstate[0], axis=1).astype(xp.int32),)
-        if spinnaker:
-            out = out + (xp.sum(sstate[0], axis=1).astype(xp.int32),)
+            carry = (now, up, ev_t, full, rr_t, rr_idx, lane0, ldn, lt0,
+                     qrep, qreb, qdn, qt0, leader, lpt, qpt, lev, qev,
+                     lhist, qhist, roster, recruit) \
+                + (hstate if hermes else ()) \
+                + (sstate if spinnaker else ()) + lat
+            out = (t_clamp, xp.sum(ldn, axis=1).astype(xp.int32),
+                   xp.sum(qdn, axis=1).astype(xp.int32),
+                   xp.sum(up, axis=1).astype(xp.int32))
+            if hermes:
+                out = out + (xp.sum(hstate[0], axis=1).astype(xp.int32),)
+            if spinnaker:
+                out = out + (xp.sum(sstate[0], axis=1).astype(xp.int32),)
         return carry, out
 
     def step_reconfig_packed(carry, s):
@@ -1111,80 +1150,96 @@ def _make_step(xp, dt_fn, advance, succ, *, n: int, P: int, rf: int,
         B = up.shape[0]               # local trials (a shard of the batch)
         t_clamp, dt, active, up, ev_t, rr_t, rr_idx = advance(
             now, up, ev_t, rr_t, rr_idx, lane0, s)
-        dt_i = t_clamp - now                                  # (B,) int32
 
         # post-event cluster state + reconfiguration up front (same rules
         # as step_reconfig, via the shared recruit_roster closure)
-        up_succ = up[:, succ]                                 # (B, P, n)
-        rup = xp.take_along_axis(up_succ, roster, axis=2)     # (B, P, rf)
-        loss_any = xp.any(qrep & ~rup, axis=2)
-        roster, new_rank, took = recruit_roster(up_succ, rup, roster)
+        with stage(xp, "lark_rank_gather"):
+            up_succ = up[:, succ]                             # (B, P, n)
+        with stage(xp, "lark_roster"):
+            rup = xp.take_along_axis(up_succ, roster, axis=2)  # (B, P, rf)
+            loss_any = xp.any(qrep & ~rup, axis=2)
+            roster, new_rank, took = recruit_roster(up_succ, rup, roster)
 
         # the single per-step eval: packed words + reconfigured roster
         # (+ carried recruit/in-flight for the contention counts).  The
         # protocol-zoo extras sit between nrep and crepsw, so the fixed
         # landmarks are out_t[:4] and the crepsw/counts tail offsets.
         ne = int(hermes) + int(spinnaker)
-        upw = xp.moveaxis(bitpack.pack_words(up_succ, xp), -1, 1)
+        with stage(xp, "lark_rank_gather"):
+            upw = xp.moveaxis(bitpack.pack_words(up_succ, xp), -1, 1)
         if bandwidth_fp is None:
-            out_t = dt_fn(upw, full, roster)
+            with stage(xp, "lark_step_eval"):
+                out_t = dt_fn(upw, full, roster)
             rate = xp.full((B, P), _REB_SCALE, dtype=xp.int32)
         else:
-            inflight = (qreb > 0) & (recruit < n)
-            out_t = dt_fn(upw, full, roster, recruit, inflight)
+            with stage(xp, "lark_node_counts"):
+                inflight = (qreb > 0) & (recruit < n)
+            with stage(xp, "lark_step_eval"):
+                out_t = dt_fn(upw, full, roster, recruit, inflight)
             counts = out_t[6 + ne]
-            k = xp.take_along_axis(counts,
-                                   xp.clip(recruit, 0, n - 1), axis=1)
-            k = xp.where(recruit < n, xp.maximum(k, 1), 1)
-            rate = xp.minimum(xp.int32(_REB_SCALE),
-                              xp.int32(bandwidth_fp) // k)
+            with stage(xp, "lark_node_counts"):
+                k = xp.take_along_axis(counts,
+                                       xp.clip(recruit, 0, n - 1), axis=1)
+                k = xp.where(recruit < n, xp.maximum(k, 1), 1)
+                rate = xp.minimum(xp.int32(_REB_SCALE),
+                                  xp.int32(bandwidth_fp) // k)
         lark, qmaj, ldr, lfull = out_t[:4]
         crepsw = out_t[5 + ne]
         repm = out_t[5] if hermes else None
         rlead = out_t[5 + int(hermes)] if spinnaker else None
 
-        lpt, qpt, qreb, qdn, qhist, qmaj_prev, rem0 = interval_pause(
-            now, dt, dt_i, ldn, qrep, qreb, qdn, qt0, lpt, qpt, qhist,
-            rate=rate)
-        if hermes:
-            hstate = hermes_interval(now, dt, dt_i, ldn, hstate)
-        if spinnaker:
-            sstate = spinnaker_interval(now, dt, dt_i, qmaj_prev, rem0,
-                                        sstate)
+        with stage(xp, "lark_protocols"):
+            dt_i = t_clamp - now                              # (B,) int32
+            lpt, qpt, qreb, qdn, qhist, qmaj_prev, rem0 = interval_pause(
+                now, dt, dt_i, ldn, qrep, qreb, qdn, qt0, lpt, qpt, qhist,
+                rate=rate)
+            if hermes:
+                hstate = hermes_interval(now, dt, dt_i, ldn, hstate)
+            if spinnaker:
+                sstate = spinnaker_interval(now, dt, dt_i, qmaj_prev, rem0,
+                                            sstate)
         lat = lat_interval(lat, dt_i, ldn, qmaj_prev, rem0)
         now = t_clamp
 
-        qreb = xp.where(loss_any, rebuild_ticks[None, :], qreb)
-        new_node = succ[xp.arange(P, dtype=xp.int32)[None, :],
-                        xp.clip(new_rank, 0, n - 1)]
-        recruit = xp.where(took, new_node,
-                           xp.where(loss_any, xp.int32(n), recruit))
+        with stage(xp, "lark_protocols"):
+            qreb = xp.where(loss_any, rebuild_ticks[None, :], qreb)
+        with stage(xp, "lark_node_counts"):
+            new_node = succ[xp.arange(P, dtype=xp.int32)[None, :],
+                            xp.clip(new_rank, 0, n - 1)]
+            recruit = xp.where(took, new_node,
+                               xp.where(loss_any, xp.int32(n), recruit))
 
-        full = xp.where(lark[:, None, :], crepsw, full)
-        ldn, lt0, leader, lpt, lev, lhist, pen = lark_transitions(
-            t_clamp, lark, ldr, lfull, ldn, lt0, leader, lpt, lev, lhist)
+        with stage(xp, "lark_step_eval"):
+            full = xp.where(lark[:, None, :], crepsw, full)
+        with stage(xp, "lark_protocols"):
+            ldn, lt0, leader, lpt, lev, lhist, pen = lark_transitions(
+                t_clamp, lark, ldr, lfull, ldn, lt0, leader, lpt, lev,
+                lhist)
         lat = lat_dirty_reset(lat, pen)
-        qdn, qt0, qev, qhist = quorum_transitions(
-            t_clamp, qmaj, qreb, qdn, qt0, qev, qhist)
-        qrep = xp.take_along_axis(up_succ, roster, axis=2)
-        if hermes:
-            hstate = hermes_post(t_clamp, lark, repm, hstate)
-        if spinnaker:
-            sstate = spinnaker_post(t_clamp, qmaj, qreb, qrep, roster,
-                                    rlead, sstate)
+        with stage(xp, "lark_protocols"):
+            qdn, qt0, qev, qhist = quorum_transitions(
+                t_clamp, qmaj, qreb, qdn, qt0, qev, qhist)
+        with stage(xp, "lark_roster"):
+            qrep = xp.take_along_axis(up_succ, roster, axis=2)
+        with stage(xp, "lark_protocols"):
+            if hermes:
+                hstate = hermes_post(t_clamp, lark, repm, hstate)
+            if spinnaker:
+                sstate = spinnaker_post(t_clamp, qmaj, qreb, qrep, roster,
+                                        rlead, sstate)
 
-        carry = (now, up, ev_t, full, rr_t, rr_idx, lane0, ldn, lt0,
-                 qrep, qreb, qdn, qt0, leader, lpt, qpt, lev, qev,
-                 lhist, qhist, roster, recruit) \
-            + (hstate if hermes else ()) \
-            + (sstate if spinnaker else ()) + lat
-        out = (t_clamp, xp.sum(ldn, axis=1).astype(xp.int32),
-               xp.sum(qdn, axis=1).astype(xp.int32),
-               xp.sum(up, axis=1).astype(xp.int32))
-        if hermes:
-            out = out + (xp.sum(hstate[0], axis=1).astype(xp.int32),)
-        if spinnaker:
-            out = out + (xp.sum(sstate[0], axis=1).astype(xp.int32),)
+            carry = (now, up, ev_t, full, rr_t, rr_idx, lane0, ldn, lt0,
+                     qrep, qreb, qdn, qt0, leader, lpt, qpt, lev, qev,
+                     lhist, qhist, roster, recruit) \
+                + (hstate if hermes else ()) \
+                + (sstate if spinnaker else ()) + lat
+            out = (t_clamp, xp.sum(ldn, axis=1).astype(xp.int32),
+                   xp.sum(qdn, axis=1).astype(xp.int32),
+                   xp.sum(up, axis=1).astype(xp.int32))
+            if hermes:
+                out = out + (xp.sum(hstate[0], axis=1).astype(xp.int32),)
+            if spinnaker:
+                out = out + (xp.sum(sstate[0], axis=1).astype(xp.int32),)
         return carry, out
 
     if rebuild_model == "reconfig":
@@ -1231,7 +1286,7 @@ def simulate_downtime_batched(
         engines: tuple = ("lark", "quorum"), lease_ticks: int = 0,
         view_change_ticks: int = 0,
         _disable_predicates: tuple = (),
-        _lat_plan=None) -> BatchedDowntimeResult:
+        _lat_plan_of=None) -> BatchedDowntimeResult:
     """Batched §6 commit-pause Monte Carlo over `trials` trajectories.
 
     Accepts the availability engine's cluster/scenario knobs unchanged
@@ -1314,345 +1369,390 @@ def simulate_downtime_batched(
     (private, DISABLE_PREDICATES) strips single transition predicates for
     the necessity tests.
 
-    _lat_plan (private; set by core/client_latency.py) appends the
-    client-latency layer's per-(trial, partition) float32 accumulators to
-    the scan carry and fills `latency_raw` on the result — the downtime
-    outputs themselves are untouched (the layer reads protocol state,
-    never writes it).
+    _lat_plan_of (private; core/client_latency.py's make_latency_plan)
+    builds the client-latency layer's tables from (seed, partitions,
+    params, max_ticks) in the call's set-up; the layer's per-(trial,
+    partition) float32 accumulators ride the scan carry and fill
+    `latency_raw` on the result — the downtime outputs themselves are
+    untouched (the layer reads protocol state, never writes it).
     """
-    _validate_batched_args(backend=backend, devices=devices, trials=trials,
-                           wave_width=wave_width, n=n)
-    if params is None:
-        params = DowntimeParams(
-            dupres_ticks=dupres_ticks, rebuild_steps=rebuild_steps,
-            hist_bins=hist_bins, rebuild_model=rebuild_model,
-            rebuild_ticks_per_gib=rebuild_ticks_per_gib,
-            size_dist=size_dist, size_skew=size_skew,
-            node_bandwidth_gibps=node_bandwidth_gibps,
-            engines=engines, lease_ticks=lease_ticks,
-            view_change_ticks=view_change_ticks)
-    dupres_ticks, rebuild_steps = params.dupres_ticks, params.rebuild_steps
-    hist_bins, rebuild_model = params.hist_bins, params.rebuild_model
-    rebuild_ticks_per_gib = params.rebuild_ticks_per_gib
-    size_dist, size_skew = params.size_dist, params.size_skew
-    node_bandwidth_gibps = params.node_bandwidth_gibps
-    reconfig = params.reconfig
-    bandwidth_shared = params.bandwidth_shared
-    engines = params.engines
-    lease_ticks = params.lease_ticks
-    view_change_ticks = params.view_change_ticks
-    hermes_on, spinnaker_on = params.hermes, params.spinnaker
-    disable = frozenset(_disable_predicates)
-    unknown = disable - set(DISABLE_PREDICATES)
-    if unknown:
-        raise ValueError(f"unknown disable predicates {sorted(unknown)}; "
-                         f"expected a subset of {DISABLE_PREDICATES}")
-    if (reconfig or bandwidth_shared) \
-            and max_ticks > (2 ** 31 - 1) // _REB_SCALE - 2:
-        raise ValueError("max_ticks too large for the fixed-point "
-                         f"catch-up countdowns (<= "
-                         f"{(2 ** 31 - 1) // _REB_SCALE - 2})")
-    shard = use_shard_map if use_shard_map is not None else devices > 1
-    B, P, horizon = trials, partitions, max_ticks
-    (xp, succ, seed_mix, geo_masks, geo_tables, dt_vec, pair_perm,
-     p_arr, dt_arr) = _engine_setup(
-        backend, n=n, partitions=P, seed=seed, p=p, downtime=downtime,
-        p_node=p_node, downtime_node=downtime_node, max_ticks=max_ticks)
-    zoo = tuple(e for e in ("hermes", "spinnaker") if e in engines)
-    spec = StepSpec(metric="downtime", rf=rf, n_real=n,
-                    rebuild_model=rebuild_model, packed=packed,
-                    dupres_ticks=dupres_ticks, rebuild_steps=rebuild_steps,
-                    engines=zoo)
+    call = next_call()
+    with span(backend, "lark.call", call=call,
+              engine="downtime" if _lat_plan_of is None else "latency",
+              trials=trials, partitions=partitions,
+              chunk_steps=chunk_steps):
+        _validate_batched_args(backend=backend, devices=devices,
+                               trials=trials, wave_width=wave_width, n=n)
+        if params is None:
+            params = DowntimeParams(
+                dupres_ticks=dupres_ticks, rebuild_steps=rebuild_steps,
+                hist_bins=hist_bins, rebuild_model=rebuild_model,
+                rebuild_ticks_per_gib=rebuild_ticks_per_gib,
+                size_dist=size_dist, size_skew=size_skew,
+                node_bandwidth_gibps=node_bandwidth_gibps,
+                engines=engines, lease_ticks=lease_ticks,
+                view_change_ticks=view_change_ticks)
+        dupres_ticks = params.dupres_ticks
+        rebuild_steps = params.rebuild_steps
+        hist_bins, rebuild_model = params.hist_bins, params.rebuild_model
+        rebuild_ticks_per_gib = params.rebuild_ticks_per_gib
+        size_dist, size_skew = params.size_dist, params.size_skew
+        node_bandwidth_gibps = params.node_bandwidth_gibps
+        reconfig = params.reconfig
+        bandwidth_shared = params.bandwidth_shared
+        engines = params.engines
+        lease_ticks = params.lease_ticks
+        view_change_ticks = params.view_change_ticks
+        hermes_on, spinnaker_on = params.hermes, params.spinnaker
+        disable = frozenset(_disable_predicates)
+        unknown = disable - set(DISABLE_PREDICATES)
+        if unknown:
+            raise ValueError(f"unknown disable predicates "
+                             f"{sorted(unknown)}; expected a subset of "
+                             f"{DISABLE_PREDICATES}")
+        if (reconfig or bandwidth_shared) \
+                and max_ticks > (2 ** 31 - 1) // _REB_SCALE - 2:
+            raise ValueError("max_ticks too large for the fixed-point "
+                             f"catch-up countdowns (<= "
+                             f"{(2 ** 31 - 1) // _REB_SCALE - 2})")
+        shard = use_shard_map if use_shard_map is not None else devices > 1
+        B, P, horizon = trials, partitions, max_ticks
+        with span(backend, "lark.setup", call=call):
+            (xp, succ, seed_mix, geo_masks, geo_tables, dt_vec, pair_perm,
+             p_arr, dt_arr) = _engine_setup(
+                backend, n=n, partitions=P, seed=seed, p=p,
+                downtime=downtime, p_node=p_node,
+                downtime_node=downtime_node, max_ticks=max_ticks)
+            zoo = tuple(e for e in ("hermes", "spinnaker")
+                        if e in engines)
+            spec = StepSpec(metric="downtime", rf=rf, n_real=n,
+                            rebuild_model=rebuild_model, packed=packed,
+                            dupres_ticks=dupres_ticks,
+                            rebuild_steps=rebuild_steps, engines=zoo)
 
-    def dt_fn(u, f, roster=None, recruit=None, active=None):
-        o = step_eval(spec, u, f, roster=roster, recruit=recruit,
-                      active=active, backend=backend, block_p=pac_block_p,
-                      block_t=block_t)
-        extras = ()
-        if o.repmask is not None:
-            extras = extras + (o.repmask,)
-        if o.rleader is not None:
-            extras = extras + (o.rleader,)
-        base = (o.lark, o.maj, o.leader, o.leader_full, o.nrep) \
-            + extras + (o.creps,)
-        return (base + (o.counts,)) if recruit is not None else base
+            def dt_fn(u, f, roster=None, recruit=None, active=None):
+                o = step_eval(spec, u, f, roster=roster, recruit=recruit,
+                              active=active, backend=backend,
+                              block_p=pac_block_p, block_t=block_t)
+                extras = ()
+                if o.repmask is not None:
+                    extras = extras + (o.repmask,)
+                if o.rleader is not None:
+                    extras = extras + (o.rleader,)
+                base = (o.lark, o.maj, o.leader, o.leader_full, o.nrep) \
+                    + extras + (o.creps,)
+                return (base + (o.counts,)) if recruit is not None \
+                    else base
 
-    rebuild_ticks = xp.asarray(_partition_rebuild_ticks(
-        seed, P, rebuild_ticks_per_gib, dist=size_dist, skew=size_skew,
-        cap=max_ticks + 1) * np.int32(_REB_SCALE)) if reconfig else None
-    bandwidth_fp = int(min(math.floor(_REB_SCALE * node_bandwidth_gibps),
-                           int(_REB_BIG))) if bandwidth_shared else None
-    cnt_fn = (lambda rec, act: _rebuild_node_counts_impl(
-        rec, act, n_real=n, backend=backend)) if bandwidth_shared else None
-    # fixed-model restart value in fixed-point work units; the horizon
-    # cap keeps rebuild_steps * _REB_SCALE inside int32 and is
-    # observationally invisible (a countdown past the horizon can never
-    # complete in-simulation), mirroring _partition_rebuild_ticks's cap
-    rebuild_fp = int(min(rebuild_steps, max_ticks + 1)) * _REB_SCALE \
-        if (bandwidth_shared and not reconfig) else None
-    advance = _make_node_advance(
-        xp, n=n, horizon=horizon, dt_vec=dt_vec, geo_masks=geo_masks,
-        geo_tables=geo_tables, seed_mix=seed_mix,
-        pair_fail_prob=pair_fail_prob, pair_perm=pair_perm,
-        restart_period=restart_period, wave_width=wave_width)
-    lat_fn = None
-    if _lat_plan is not None:
-        lat_pow = xp.asarray(_lat_plan.pow_tables)
-        lat_kf = xp.asarray(_lat_plan.kf)
-        lat_lamw = xp.asarray(_lat_plan.lamw)
-        lat_nbins, lat_slo = _lat_plan.nbins, _lat_plan.slo_ticks
+            rebuild_ticks = xp.asarray(_partition_rebuild_ticks(
+                seed, P, rebuild_ticks_per_gib, dist=size_dist,
+                skew=size_skew, cap=max_ticks + 1)
+                * np.int32(_REB_SCALE)) if reconfig else None
+            bandwidth_fp = int(min(
+                math.floor(_REB_SCALE * node_bandwidth_gibps),
+                int(_REB_BIG))) if bandwidth_shared else None
+            cnt_fn = (lambda rec, act: _rebuild_node_counts_impl(
+                rec, act, n_real=n, backend=backend)) \
+                if bandwidth_shared else None
+            # fixed-model restart value in fixed-point work units; the
+            # horizon cap keeps rebuild_steps * _REB_SCALE inside int32 and
+            # is observationally invisible (a countdown past the horizon
+            # can never complete in-simulation), mirroring
+            # _partition_rebuild_ticks's cap
+            rebuild_fp = int(min(rebuild_steps, max_ticks + 1)) \
+                * _REB_SCALE if (bandwidth_shared and not reconfig) else None
+            advance = _make_node_advance(
+                xp, n=n, horizon=horizon, dt_vec=dt_vec,
+                geo_masks=geo_masks, geo_tables=geo_tables,
+                seed_mix=seed_mix,
+                pair_fail_prob=pair_fail_prob, pair_perm=pair_perm,
+                restart_period=restart_period, wave_width=wave_width)
+            lat_plan = None if _lat_plan_of is None \
+                else _lat_plan_of(seed, P, params, max_ticks)
+            lat_fn = None
+            if lat_plan is not None:
+                lat_pow = xp.asarray(lat_plan.pow_tables)
+                lat_kf = xp.asarray(lat_plan.kf)
+                lat_lamw = xp.asarray(lat_plan.lamw)
+                lat_nbins, lat_slo = lat_plan.nbins, lat_plan.slo_ticks
 
-        def lat_fn(lat, dt_i, avail, qok, rem):
-            nd, di, hi, si, qi = client_latency_step(
-                lat[0], dt_i, avail, qok, rem, pow_tables=lat_pow,
-                kf=lat_kf, lamw=lat_lamw, nbins=lat_nbins,
-                slo_ticks=lat_slo, backend=backend)
-            return (nd, lat[1] + di, lat[2] + hi, lat[3] + si,
-                    lat[4] + qi)
-    step = _make_step(xp, dt_fn, advance, succ, n=n, P=P, rf=rf,
-                      dupres_ticks=dupres_ticks,
-                      rebuild_steps=rebuild_steps, hist_bins=hist_bins,
-                      rebuild_model=rebuild_model,
-                      rebuild_ticks=rebuild_ticks,
-                      bandwidth_fp=bandwidth_fp, cnt_fn=cnt_fn,
-                      rebuild_fp=rebuild_fp,
-                      packed=packed, lat_fn=lat_fn, engines=zoo,
-                      lease_ticks=lease_ticks,
-                      view_change_ticks=view_change_ticks,
-                      disable=disable)
+                def lat_fn(lat, dt_i, avail, qok, rem):
+                    nd, di, hi, si, qi = client_latency_step(
+                        lat[0], dt_i, avail, qok, rem, pow_tables=lat_pow,
+                        kf=lat_kf, lamw=lat_lamw, nbins=lat_nbins,
+                        slo_ticks=lat_slo, backend=backend)
+                    return (nd, lat[1] + di, lat[2] + hi, lat[3] + si,
+                            lat[4] + qi)
+            step = _make_step(xp, dt_fn, advance, succ, n=n, P=P, rf=rf,
+                              dupres_ticks=dupres_ticks,
+                              rebuild_steps=rebuild_steps,
+                              hist_bins=hist_bins,
+                              rebuild_model=rebuild_model,
+                              rebuild_ticks=rebuild_ticks,
+                              bandwidth_fp=bandwidth_fp, cnt_fn=cnt_fn,
+                              rebuild_fp=rebuild_fp,
+                              packed=packed, lat_fn=lat_fn, engines=zoo,
+                              lease_ticks=lease_ticks,
+                              view_change_ticks=view_change_ticks,
+                              disable=disable)
 
-    # initial state: everyone up, roster replicas full, both protocols
-    # evaluated once at t=0 (identical to the availability engine's init;
-    # the t=0 roster is [0..rf-1] per partition, so the non-roster init
-    # evaluation is exact for both rebuild models)
-    lane0, up0, ev0, rr_t0 = _initial_node_state(
-        xp, B=B, n=n, seed_mix=seed_mix, geo_masks=geo_masks,
-        geo_tables=geo_tables, restart_period=restart_period,
-        horizon=horizon)
-    full0, outs0 = _initial_full_state(
-        xp, backend, dt_fn, up0, succ, B=B, P=P, n=n, rf=rf, packed=packed)
-    lark0 = outs0[0].reshape(B, P)
-    qmaj0 = outs0[1].reshape(B, P)
-    ldr0 = outs0[2].reshape(B, P)
-    zi = xp.zeros((B,), dtype=xp.int32)
-    zf = xp.zeros((B,), dtype=xp.float32)
-    zbp = xp.zeros((B, P), dtype=xp.int32)
-    zh = xp.zeros((B, hist_bins), dtype=xp.int32)
-    carry = (zi, up0, ev0, full0, rr_t0, zi, lane0,
-             ~lark0, zbp,                              # ldn, lt0
-             up0[:, succ[:, :rf]],                     # qrep (all up)
-             zbp,                                      # qreb
-             ~qmaj0, zbp,                              # qdn, qt0
-             ldr0.astype(xp.int32),                    # leader
-             zf, zf, zi, zi, zh, zh)
-    if reconfig:
-        roster0 = xp.broadcast_to(
-            xp.arange(rf, dtype=xp.int32)[None, None, :], (B, P, rf))
-        if backend == "numpy":
-            roster0 = np.ascontiguousarray(roster0)
-        # no catch-up in flight at t=0, so no recruit node to ingest on
-        recruit0 = xp.full((B, P), n, dtype=xp.int32)
-        carry = carry + (roster0, recruit0)
-    elif bandwidth_shared:
-        # fixed model with bandwidth contention carries only the
-        # rebuilding-node leaf (the replica set itself is static)
-        carry = carry + (xp.full((B, P), n, dtype=xp.int32),)
-    h0 = len(carry)                   # hermes leaves start here (if any)
-    if hermes_on:
-        # the t=0 membership view is the kernel's repmask on the initial
-        # state; the pause mask starts exactly at LARK's (no lease runs)
-        hmask0 = outs0[5].reshape(B, P).astype(xp.int32)
-        carry = carry + (~lark0, zbp, hmask0, zbp, zf, zi, zh)
-    s0_i = len(carry)                 # spinnaker leaves start here
-    if spinnaker_on:
-        # rank 0 leads at t=0 (everyone up, roster [0..rf-1]); no view
-        # change in flight, so the pause mask starts at the quorum
-        # baseline's
-        carry = carry + (~qmaj0, zbp, zbp, zbp, zf, zi, zh)
-    lat_i = len(carry)                # lat leaves ride at the carry tail
-    if _lat_plan is not None:
-        nb = _lat_plan.kf.shape[0]
-        lz_nb = xp.zeros((B, P, nb), dtype=xp.float32)
-        lz_hb = xp.zeros((B, P, _lat_plan.nbins), dtype=xp.float32)
-        lz_bp = xp.zeros((B, P), dtype=xp.float32)
-        # dirty starts clean (no leader has changed yet), charges at zero
-        carry = carry + (lz_nb, lz_nb, lz_hb, lz_bp, lz_bp)
+            # initial state: everyone up, roster replicas full, both
+            # protocols evaluated once at t=0 (identical to the
+            # availability engine's init; the t=0 roster is [0..rf-1] per
+            # partition, so the non-roster init evaluation is exact for
+            # both rebuild models)
+            lane0, up0, ev0, rr_t0 = _initial_node_state(
+                xp, B=B, n=n, seed_mix=seed_mix, geo_masks=geo_masks,
+                geo_tables=geo_tables, restart_period=restart_period,
+                horizon=horizon)
+            full0, outs0 = _initial_full_state(
+                xp, backend, dt_fn, up0, succ, B=B, P=P, n=n, rf=rf,
+                packed=packed)
+            lark0 = outs0[0].reshape(B, P)
+            qmaj0 = outs0[1].reshape(B, P)
+            ldr0 = outs0[2].reshape(B, P)
+            zi = xp.zeros((B,), dtype=xp.int32)
+            zf = xp.zeros((B,), dtype=xp.float32)
+            zbp = xp.zeros((B, P), dtype=xp.int32)
+            zh = xp.zeros((B, hist_bins), dtype=xp.int32)
+            carry = (zi, up0, ev0, full0, rr_t0, zi, lane0,
+                     ~lark0, zbp,                          # ldn, lt0
+                     up0[:, succ[:, :rf]],                 # qrep (all up)
+                     zbp,                                  # qreb
+                     ~qmaj0, zbp,                          # qdn, qt0
+                     ldr0.astype(xp.int32),                # leader
+                     zf, zf, zi, zi, zh, zh)
+            if reconfig:
+                roster0 = xp.broadcast_to(
+                    xp.arange(rf, dtype=xp.int32)[None, None, :],
+                    (B, P, rf))
+                if backend == "numpy":
+                    roster0 = np.ascontiguousarray(roster0)
+                # no catch-up in flight at t=0, so no recruit node to
+                # ingest on
+                recruit0 = xp.full((B, P), n, dtype=xp.int32)
+                carry = carry + (roster0, recruit0)
+            elif bandwidth_shared:
+                # fixed model with bandwidth contention carries only the
+                # rebuilding-node leaf (the replica set itself is static)
+                carry = carry + (xp.full((B, P), n, dtype=xp.int32),)
+            h0 = len(carry)             # hermes leaves start here (if any)
+            if hermes_on:
+                # the t=0 membership view is the kernel's repmask on the
+                # initial state; the pause mask starts exactly at LARK's
+                # (no lease runs)
+                hmask0 = outs0[5].reshape(B, P).astype(xp.int32)
+                carry = carry + (~lark0, zbp, hmask0, zbp, zf, zi, zh)
+            s0_i = len(carry)           # spinnaker leaves start here
+            if spinnaker_on:
+                # rank 0 leads at t=0 (everyone up, roster [0..rf-1]); no
+                # view change in flight, so the pause mask starts at the
+                # quorum baseline's
+                carry = carry + (~qmaj0, zbp, zbp, zbp, zf, zi, zh)
+            lat_i = len(carry)          # lat leaves ride at the carry tail
+            if lat_plan is not None:
+                nb = lat_plan.kf.shape[0]
+                lz_nb = xp.zeros((B, P, nb), dtype=xp.float32)
+                lz_hb = xp.zeros((B, P, lat_plan.nbins), dtype=xp.float32)
+                lz_bp = xp.zeros((B, P), dtype=xp.float32)
+                # dirty starts clean (no leader has changed yet), charges
+                # at zero
+                carry = carry + (lz_nb, lz_nb, lz_hb, lz_bp, lz_bp)
 
-    if backend != "numpy":
-        import jax.numpy as jnp
-        run_chunk = _make_chunk_runner(step, carry, chunk_steps=chunk_steps,
-                                       devices=devices, shard=shard,
-                                       n_outputs=4 + int(hermes_on)
-                                       + int(spinnaker_on))
+            if max_steps is None:
+                max_steps = _default_max_steps(
+                    p_arr, dt_arr, n=n, horizon=horizon,
+                    restart_period=restart_period)
 
-    if max_steps is None:
-        max_steps = _default_max_steps(p_arr, dt_arr, n=n, horizon=horizon,
-                                       restart_period=restart_period)
+            # per-chunk accumulator reset map: the base protocol
+            # accumulators at fixed offsets 14..19 plus, when enabled, each
+            # zoo engine's (pause-time, events, histogram) leaves at offset
+            # +4..+6 of its block
+            acc_reset = {14: zf, 15: zf, 16: zi, 17: zi, 18: zh, 19: zh}
+            if hermes_on:
+                acc_reset.update({h0 + 4: zf, h0 + 5: zi, h0 + 6: zh})
+            if spinnaker_on:
+                acc_reset.update({s0_i + 4: zf, s0_i + 5: zi, s0_i + 6: zh})
 
-    # per-chunk accumulator reset map: the base protocol accumulators at
-    # fixed offsets 14..19 plus, when enabled, each zoo engine's
-    # (pause-time, events, histogram) leaves at offset +4..+6 of its block
-    acc_reset = {14: zf, 15: zf, 16: zi, 17: zi, 18: zh, 19: zh}
-    if hermes_on:
-        acc_reset.update({h0 + 4: zf, h0 + 5: zi, h0 + 6: zh})
-    if spinnaker_on:
-        acc_reset.update({s0_i + 4: zf, s0_i + 5: zi, s0_i + 6: zh})
+            lpt_tot = np.zeros(B)
+            qpt_tot = np.zeros(B)
+            lev_tot = qev_tot = 0
+            lhist_tot = np.zeros(hist_bins, dtype=np.int64)
+            qhist_tot = np.zeros(hist_bins, dtype=np.int64)
+            if hermes_on:
+                hpt_tot = np.zeros(B)
+                hev_tot = 0
+                hhist_tot = np.zeros(hist_bins, dtype=np.int64)
+            if spinnaker_on:
+                spt_tot = np.zeros(B)
+                sev_tot = 0
+                shist_tot = np.zeros(hist_bins, dtype=np.int64)
+            if lat_plan is not None:
+                lat_dup = np.zeros((B, lat_plan.kf.shape[0]))
+                lat_qhist = np.zeros((B, lat_plan.nbins))
+                lat_qslo = np.zeros(B)
+                lat_qsum = np.zeros(B)
+                lat_wfp = None
+                if lat_plan.wfp is not None:
+                    # skewed write mix: pool a second,
+                    # write-fraction-weighted view of the same dup charges
+                    # (hermes pays dup-res on writes only, so its share is
+                    # per-partition under write_skew)
+                    lat_wfp = np.asarray(lat_plan.wfp, dtype=np.float64)
+                    lat_dupw = np.zeros((B, lat_plan.kf.shape[0]))
 
-    lpt_tot = np.zeros(B)
-    qpt_tot = np.zeros(B)
-    lev_tot = qev_tot = 0
-    lhist_tot = np.zeros(hist_bins, dtype=np.int64)
-    qhist_tot = np.zeros(hist_bins, dtype=np.int64)
-    if hermes_on:
-        hpt_tot = np.zeros(B)
-        hev_tot = 0
-        hhist_tot = np.zeros(hist_bins, dtype=np.int64)
-    if spinnaker_on:
-        spt_tot = np.zeros(B)
-        sev_tot = 0
-        shist_tot = np.zeros(hist_bins, dtype=np.int64)
-    if _lat_plan is not None:
-        lat_dup = np.zeros((B, _lat_plan.kf.shape[0]))
-        lat_qhist = np.zeros((B, _lat_plan.nbins))
-        lat_qslo = np.zeros(B)
-        lat_qsum = np.zeros(B)
-        lat_wfp = None
-        if _lat_plan.wfp is not None:
-            # skewed write mix: pool a second, write-fraction-weighted
-            # view of the same dup charges (hermes pays dup-res on writes
-            # only, so its share is per-partition under write_skew)
-            lat_wfp = np.asarray(_lat_plan.wfp, dtype=np.float64)
-            lat_dupw = np.zeros((B, _lat_plan.kf.shape[0]))
-    traj = [] if trajectory else None
-    stopped = False
-    s0 = 1
-    while s0 < max_steps:
-        if backend == "numpy":
-            carry, ys = _run_chunk_numpy(step, carry, s0, chunk_steps)
-        else:
-            carry, ys = run_chunk(carry, jnp.int32(s0))
-        s0 += chunk_steps
-        if trajectory:
-            traj.append(tuple(np.asarray(c) for c in ys))
-        # drain per-chunk accumulators into float64/int totals
-        now = np.asarray(carry[0], dtype=np.int64)
-        lpt_tot += np.asarray(carry[14], dtype=np.float64)
-        qpt_tot += np.asarray(carry[15], dtype=np.float64)
-        lev_tot += int(np.asarray(carry[16]).sum())
-        qev_tot += int(np.asarray(carry[17]).sum())
-        lhist_tot += np.asarray(carry[18], dtype=np.int64).sum(axis=0)
-        qhist_tot += np.asarray(carry[19], dtype=np.int64).sum(axis=0)
-        if hermes_on:
-            hpt_tot += np.asarray(carry[h0 + 4], dtype=np.float64)
-            hev_tot += int(np.asarray(carry[h0 + 5]).sum())
-            hhist_tot += np.asarray(carry[h0 + 6],
-                                    dtype=np.int64).sum(axis=0)
-        if spinnaker_on:
-            spt_tot += np.asarray(carry[s0_i + 4], dtype=np.float64)
-            sev_tot += int(np.asarray(carry[s0_i + 5]).sum())
-            shist_tot += np.asarray(carry[s0_i + 6],
-                                    dtype=np.int64).sum(axis=0)
-        if _lat_plan is not None:
-            # pool the per-(trial, partition) float32 charge accumulators
-            # over partitions here, host-side in float64 — a fixed
-            # summation order independent of backend and device sharding
-            # (the dirty fractions persist; the charges restart per chunk)
-            lt_ = carry[lat_i:]
-            lat_dup += _pool_partitions(lt_[1])
-            if lat_wfp is not None:
-                lat_dupw += _pool_partitions(lt_[1], lat_wfp[None, :, None])
-            lat_qhist += _pool_partitions(lt_[2])
-            lat_qslo += _pool_partitions(lt_[3])
-            lat_qsum += _pool_partitions(lt_[4])
-            carry = carry[:lat_i] + (lt_[0], lz_nb, lz_hb, lz_bp, lz_bp)
-        carry = tuple(acc_reset.get(i, c) for i, c in enumerate(carry))
-        if (now >= horizon).all():
-            break
-        # pooled CI early stop, mirroring the availability engine's rule
-        # (nominal binomial width; reported CIs use across-trial spread)
-        if now.mean() >= min_ticks and lev_tot >= min_events \
-                and qev_tot >= min_events:
-            pt = float(P) * float(now.sum())
-            u_l = min(lpt_tot.sum() / pt, 1.0)
-            u_q = min(qpt_tot.sum() / pt, 1.0)
-            hw_l = 1.96 * math.sqrt(max(u_l * (1 - u_l), 1e-30) / pt)
-            hw_q = 1.96 * math.sqrt(max(u_q * (1 - u_q), 1e-30) / pt)
-            if hw_l <= max(eps_abs, eps_rel * u_l) and \
-                    hw_q <= max(eps_abs, eps_rel * u_q):
-                stopped = True
+        traj = [] if trajectory else None
+        stopped = False
+        if backend != "numpy":
+            import jax.numpy as jnp
+        s0 = 1
+        chunk = 0
+        while s0 < max_steps:
+            if backend == "numpy":
+                carry, ys = _run_chunk_numpy(step, carry, s0, chunk_steps)
+            elif chunk == 0:
+                with span(backend, "lark.chunk_program", call=call):
+                    run_chunk = _make_chunk_runner(
+                        step, carry, chunk_steps=chunk_steps, devices=devices,
+                        shard=shard,
+                        n_outputs=4 + int(hermes_on) + int(spinnaker_on))
+                    carry, ys = run_chunk(carry, jnp.int32(s0))
+            else:
+                with span(backend, "lark.dispatch", call=call, chunk=chunk,
+                          s0=s0):
+                    carry, ys = run_chunk(carry, jnp.int32(s0))
+            s0 += chunk_steps
+            with span(backend, "lark.drain", call=call, chunk=chunk) as drain:
+                if trajectory:
+                    traj.append(tuple(np.asarray(c) for c in ys))
+                # drain per-chunk accumulators into float64/int totals
+                now = np.asarray(carry[0], dtype=np.int64)
+                lpt_tot += np.asarray(carry[14], dtype=np.float64)
+                qpt_tot += np.asarray(carry[15], dtype=np.float64)
+                lev_tot += int(np.asarray(carry[16]).sum())
+                qev_tot += int(np.asarray(carry[17]).sum())
+                lhist_tot += np.asarray(carry[18], dtype=np.int64).sum(axis=0)
+                qhist_tot += np.asarray(carry[19], dtype=np.int64).sum(axis=0)
+                if hermes_on:
+                    hpt_tot += np.asarray(carry[h0 + 4], dtype=np.float64)
+                    hev_tot += int(np.asarray(carry[h0 + 5]).sum())
+                    hhist_tot += np.asarray(carry[h0 + 6],
+                                            dtype=np.int64).sum(axis=0)
+                if spinnaker_on:
+                    spt_tot += np.asarray(carry[s0_i + 4], dtype=np.float64)
+                    sev_tot += int(np.asarray(carry[s0_i + 5]).sum())
+                    shist_tot += np.asarray(carry[s0_i + 6],
+                                            dtype=np.int64).sum(axis=0)
+                if lat_plan is not None:
+                    # pool the per-(trial, partition) float32 charge
+                    # accumulators over partitions here, host-side in
+                    # float64 — a fixed summation order independent of
+                    # backend and device sharding (the dirty fractions
+                    # persist; the charges restart per chunk)
+                    lt_ = carry[lat_i:]
+                    lat_dup += _pool_partitions(lt_[1])
+                    if lat_wfp is not None:
+                        lat_dupw += _pool_partitions(
+                            lt_[1], lat_wfp[None, :, None])
+                    lat_qhist += _pool_partitions(lt_[2])
+                    lat_qslo += _pool_partitions(lt_[3])
+                    lat_qsum += _pool_partitions(lt_[4])
+                    carry = carry[:lat_i] + (lt_[0], lz_nb, lz_hb, lz_bp,
+                                             lz_bp)
+                carry = tuple(acc_reset.get(i, c) for i, c in enumerate(carry))
+                annotate(drain, ticks=float(now.mean()))
+            with span(backend, "lark.stop_test", call=call,
+                      chunk=chunk) as stop_test:
+                done = bool((now >= horizon).all())
+                # pooled CI early stop, mirroring the availability engine's
+                # rule (nominal binomial width; reported CIs use across-trial
+                # spread)
+                if not done and now.mean() >= min_ticks \
+                        and lev_tot >= min_events and qev_tot >= min_events:
+                    pt = float(P) * float(now.sum())
+                    u_l = min(lpt_tot.sum() / pt, 1.0)
+                    u_q = min(qpt_tot.sum() / pt, 1.0)
+                    hw_l = 1.96 * math.sqrt(max(u_l * (1 - u_l), 1e-30) / pt)
+                    hw_q = 1.96 * math.sqrt(max(u_q * (1 - u_q), 1e-30) / pt)
+                    stopped = done = bool(
+                        hw_l <= max(eps_abs, eps_rel * u_l)
+                        and hw_q <= max(eps_abs, eps_rel * u_q))
+                annotate(stop_test, stopped=done)
+            if done:
                 break
+            chunk += 1
 
-    now = np.maximum(np.asarray(carry[0], dtype=np.int64), 1)
-    pt_b = P * now.astype(np.float64)
-    pt = float(pt_b.sum())
-    # fractions by construction, except the instantaneous dup-res charge
-    # can overshoot wall time under extreme dupres_ticks — clip so the
-    # reported values and the binomial u*(1-u) CI terms stay meaningful
-    u_l = min(float(lpt_tot.sum()) / pt, 1.0)
-    u_q = min(float(qpt_tot.sum()) / pt, 1.0)
-    u_l_trials = np.minimum(lpt_tot / pt_b, 1.0)
-    u_q_trials = np.minimum(qpt_tot / pt_b, 1.0)
-    hw_l = hw_q = 0.0
-    if B >= 3:
-        t = t975(B - 1) / math.sqrt(B)
-        hw_l = t * float(u_l_trials.std(ddof=1))
-        hw_q = t * float(u_q_trials.std(ddof=1))
-    traj_out = None
-    if trajectory:
-        names = ["times", "paused_lark", "paused_quorum", "nodes_up"]
-        if hermes_on:
-            names.append("paused_hermes")
-        if spinnaker_on:
-            names.append("paused_spinnaker")
-        cols = [np.concatenate([c[i] for c in traj])
-                for i in range(len(names))]
-        traj_out = dict(zip(names, cols))
-    lat_raw = None
-    if _lat_plan is not None:
-        lat_raw = {"dup": lat_dup, "qhist": lat_qhist, "qslo": lat_qslo,
-                   "qsum": lat_qsum, "now": now.copy()}
-        if lat_wfp is not None:
-            lat_raw["dupw"] = lat_dupw
-
-    def _engine_stats(pt_tot):
-        u = min(float(pt_tot.sum()) / pt, 1.0)
-        u_trials = np.minimum(pt_tot / pt_b, 1.0)
-        hw = 0.0
+        now = np.maximum(np.asarray(carry[0], dtype=np.int64), 1)
+        pt_b = P * now.astype(np.float64)
+        pt = float(pt_b.sum())
+        # fractions by construction, except the instantaneous dup-res charge
+        # can overshoot wall time under extreme dupres_ticks — clip so the
+        # reported values and the binomial u*(1-u) CI terms stay meaningful
+        u_l = min(float(lpt_tot.sum()) / pt, 1.0)
+        u_q = min(float(qpt_tot.sum()) / pt, 1.0)
+        u_l_trials = np.minimum(lpt_tot / pt_b, 1.0)
+        u_q_trials = np.minimum(qpt_tot / pt_b, 1.0)
+        hw_l = hw_q = 0.0
         if B >= 3:
-            hw = t975(B - 1) / math.sqrt(B) * float(u_trials.std(ddof=1))
-        ci = max(hw, 1.96 * math.sqrt(max(u * (1 - u), 1e-30) / pt))
-        return u, ci, u_trials
+            t = t975(B - 1) / math.sqrt(B)
+            hw_l = t * float(u_l_trials.std(ddof=1))
+            hw_q = t * float(u_q_trials.std(ddof=1))
+        traj_out = None
+        if trajectory:
+            names = ["times", "paused_lark", "paused_quorum", "nodes_up"]
+            if hermes_on:
+                names.append("paused_hermes")
+            if spinnaker_on:
+                names.append("paused_spinnaker")
+            cols = [np.concatenate([c[i] for c in traj])
+                    for i in range(len(names))]
+            traj_out = dict(zip(names, cols))
+        lat_raw = None
+        if lat_plan is not None:
+            lat_raw = {"dup": lat_dup, "qhist": lat_qhist, "qslo": lat_qslo,
+                       "qsum": lat_qsum, "now": now.copy()}
+            if lat_wfp is not None:
+                lat_raw["dupw"] = lat_dupw
 
-    zoo_kw = {}
-    if hermes_on:
-        u_h, ci_h, u_h_trials = _engine_stats(hpt_tot)
-        zoo_kw.update(pause_hermes=u_h, ci_hermes=ci_h,
-                      hermes_events=hev_tot, hist_hermes=hhist_tot,
-                      pause_hermes_trials=u_h_trials)
-    if spinnaker_on:
-        u_s, ci_s, u_s_trials = _engine_stats(spt_tot)
-        zoo_kw.update(pause_spinnaker=u_s, ci_spinnaker=ci_s,
-                      spinnaker_events=sev_tot, hist_spinnaker=shist_tot,
-                      pause_spinnaker_trials=u_s_trials)
-    return BatchedDowntimeResult(
-        p=p, rf=rf, n=n, partitions=P, trials=B, backend=backend,
-        ticks=int(now.mean()), pause_lark=u_l, pause_quorum=u_q,
-        lark_events=lev_tot, quorum_events=qev_tot,
-        ci_lark=max(hw_l,
-                    1.96 * math.sqrt(max(u_l * (1 - u_l), 1e-30) / pt)),
-        ci_quorum=max(hw_q,
-                      1.96 * math.sqrt(max(u_q * (1 - u_q), 1e-30) / pt)),
-        dupres_ticks=dupres_ticks, rebuild_steps=rebuild_steps,
-        stopped_early=stopped, devices=devices,
-        rebuild_model=rebuild_model,
-        rebuild_ticks_per_gib=rebuild_ticks_per_gib if reconfig else 0,
-        size_dist=size_dist if reconfig else "uniform",
-        size_skew=size_skew if size_dist in ("zipf", "lognormal") else 0.0,
-        node_bandwidth_gibps=node_bandwidth_gibps,
-        hist_edges=np.asarray([1 << k for k in range(hist_bins)],
-                              dtype=np.int64),
-        hist_lark=lhist_tot, hist_quorum=qhist_tot,
-        pause_lark_trials=u_l_trials, pause_quorum_trials=u_q_trials,
-        engines=engines, lease_ticks=lease_ticks,
-        view_change_ticks=view_change_ticks,
-        trajectory=traj_out, latency_raw=lat_raw, **zoo_kw)
+        def _engine_stats(pt_tot):
+            u = min(float(pt_tot.sum()) / pt, 1.0)
+            u_trials = np.minimum(pt_tot / pt_b, 1.0)
+            hw = 0.0
+            if B >= 3:
+                hw = t975(B - 1) / math.sqrt(B) * float(u_trials.std(ddof=1))
+            ci = max(hw, 1.96 * math.sqrt(max(u * (1 - u), 1e-30) / pt))
+            return u, ci, u_trials
+
+        zoo_kw = {}
+        if hermes_on:
+            u_h, ci_h, u_h_trials = _engine_stats(hpt_tot)
+            zoo_kw.update(pause_hermes=u_h, ci_hermes=ci_h,
+                          hermes_events=hev_tot, hist_hermes=hhist_tot,
+                          pause_hermes_trials=u_h_trials)
+        if spinnaker_on:
+            u_s, ci_s, u_s_trials = _engine_stats(spt_tot)
+            zoo_kw.update(pause_spinnaker=u_s, ci_spinnaker=ci_s,
+                          spinnaker_events=sev_tot, hist_spinnaker=shist_tot,
+                          pause_spinnaker_trials=u_s_trials)
+        return BatchedDowntimeResult(
+            p=p, rf=rf, n=n, partitions=P, trials=B, backend=backend,
+            ticks=int(now.mean()), pause_lark=u_l, pause_quorum=u_q,
+            lark_events=lev_tot, quorum_events=qev_tot,
+            ci_lark=max(hw_l,
+                        1.96 * math.sqrt(max(u_l * (1 - u_l), 1e-30) / pt)),
+            ci_quorum=max(hw_q,
+                          1.96 * math.sqrt(max(u_q * (1 - u_q), 1e-30) / pt)),
+            dupres_ticks=dupres_ticks, rebuild_steps=rebuild_steps,
+            stopped_early=stopped, devices=devices,
+            rebuild_model=rebuild_model,
+            rebuild_ticks_per_gib=rebuild_ticks_per_gib if reconfig else 0,
+            size_dist=size_dist if reconfig else "uniform",
+            size_skew=size_skew if size_dist in ("zipf", "lognormal") else 0.0,
+            node_bandwidth_gibps=node_bandwidth_gibps,
+            hist_edges=np.asarray([1 << k for k in range(hist_bins)],
+                                  dtype=np.int64),
+            hist_lark=lhist_tot, hist_quorum=qhist_tot,
+            pause_lark_trials=u_l_trials, pause_quorum_trials=u_q_trials,
+            engines=engines, lease_ticks=lease_ticks,
+            view_change_ticks=view_change_ticks,
+            trajectory=traj_out, latency_raw=lat_raw, **zoo_kw)

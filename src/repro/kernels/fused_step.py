@@ -102,6 +102,7 @@ def fused_pac_eval(upw, fullw, *, rf: int, voters: int, n_real: int,
             jax.ShapeDtypeStruct((B, W, P), jnp.uint32),
         ],
         interpret=interpret,
+        name="lark_fused_pac",
     )(upw, fullw)
 
 
@@ -234,4 +235,5 @@ def fused_downtime_eval(upw, fullw, *, rf: int, n_real: int, block_t: int,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="lark_fused_downtime",
     )(*operands)

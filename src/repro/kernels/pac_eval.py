@@ -131,6 +131,7 @@ def pac_eval(up_succ, full_succ, *, rf: int, voters: int, n_real: int,
             jax.ShapeDtypeStruct((P, n_pad), jnp.bool_),
         ],
         interpret=interpret,
+        name="lark_pac_eval",
     )(up_succ, full_succ)
     return lark[:, 0], maj[:, 0], creps
 
@@ -298,6 +299,7 @@ def node_count(recruit, active, *, n_real: int, interpret: bool = False,
         out_specs=pl.BlockSpec((block_b, n_lanes), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, n_lanes), jnp.int32),
         interpret=interpret,
+        name="lark_node_count",
     )(recruit.astype(jnp.int32), active.astype(jnp.int32))
 
 
@@ -366,6 +368,7 @@ def latency_charge(dirty, decay, avail, qok, rem, dt, lamw, kf, *,
             jax.ShapeDtypeStruct((B, 1, P), jnp.float32),
         ],
         interpret=interpret,
+        name="lark_latency_charge",
     )(dirty, decay,
       jnp.broadcast_to(kf.astype(jnp.float32)[None, :, None], (1, NB, P)),
       rows(avail, jnp.int32), rows(qok, jnp.int32), rows(rem, jnp.int32),
@@ -419,5 +422,6 @@ def downtime_eval(up_succ, full_succ, *, rf: int, n_real: int,
         + [col(jnp.int32)] * n_extra
         + [jax.ShapeDtypeStruct((P, n_pad), jnp.bool_)],
         interpret=interpret,
+        name="lark_downtime_eval",
     )(*operands)
     return tuple(o[:, 0] for o in outs[:-1]) + (outs[-1],)
