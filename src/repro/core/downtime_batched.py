@@ -522,6 +522,40 @@ def _hist_add(xp, hist_bins: int, hist, mask, d):
     return hist + xp.sum(oh, axis=1).astype(xp.int32)
 
 
+# -- per-(trial, partition) lookups.  Each is a compare against its static
+# lane range plus a reduce, never a gather with one index an element: on a
+# TPU v5e such a gather costs about 10 ns an output, a fused lane compare
+# about 0.01 ns a lane.
+
+def _seat_up(xp, up_succ, roster):
+    """up_succ[b, p, roster[b, p, j]] for every seat j: (B, P, n) bool
+    rank-space up mask + (B, P, rf) roster ranks in [0, n) ->
+    (B, P, rf) bool."""
+    lanes = xp.arange(up_succ.shape[2], dtype=xp.int32)
+    return xp.stack([xp.any(up_succ & (lanes == roster[:, :, j:j + 1]),
+                            axis=2)
+                     for j in range(roster.shape[2])], axis=2)
+
+
+def _count_at(xp, counts, recruit):
+    """counts[b, recruit[b, p]]: (B, n) int32 per-node counts + (B, P)
+    node ids -> (B, P) int32, 0 where recruit is the no-ingest-node
+    sentinel n."""
+    lanes = xp.arange(counts.shape[1], dtype=xp.int32)
+    return xp.sum(xp.where(lanes == recruit[:, :, None],
+                           counts[:, None, :], 0), axis=2).astype(xp.int32)
+
+
+def _rank_node(xp, succ, rank, w: int):
+    """succ[p, clip(rank[b, p], 0, w - 1)]: (P, n) succession matrix +
+    (B, P) succession ranks -> (B, P) int32 node ids, over the static
+    rank lanes 0..w-1."""
+    rank = xp.clip(rank, 0, w - 1)
+    lanes = xp.arange(w, dtype=xp.int32)
+    return xp.sum(xp.where(lanes == rank[:, :, None], succ[:, :w][None], 0),
+                  axis=2).astype(xp.int32)
+
+
 def _make_step(xp, dt_fn, advance, succ, *, n: int, P: int, rf: int,
                dupres_ticks: int, rebuild_steps: int, hist_bins: int,
                rebuild_model: str = "fixed", rebuild_ticks=None,
@@ -539,6 +573,15 @@ def _make_step(xp, dt_fn, advance, succ, *, n: int, P: int, rf: int,
 
     def hist_add(hist, mask, d):
         return _hist_add(xp, hist_bins, hist, mask, d)
+
+    def share_rate(counts, recruit):
+        """Each partition's catch-up rate in _REB_SCALE units a tick: the
+        bandwidth share its ingest node grants over the interval, from
+        the (B, n) in-flight counts.  A catch-up with no known ingest
+        node (recruit == n) runs uncontended."""
+        k = xp.where(recruit < n,
+                     xp.maximum(_count_at(xp, counts, recruit), 1), 1)
+        return xp.minimum(xp.int32(_REB_SCALE), xp.int32(bandwidth_fp) // k)
 
     def lat_interval(lat, dt_i, ldn, qmaj_prev, rem):
         """Charge the client-latency layer for one event interval from
@@ -885,13 +928,7 @@ def _make_step(xp, dt_fn, advance, succ, *, n: int, P: int, rf: int,
                     repm = out_t[5].reshape(B, P)
         counts = out_t[-1]
         with stage(xp, "lark_node_counts"):
-            kk = xp.take_along_axis(counts,
-                                    xp.clip(recruit, 0, n - 1), axis=1)
-            # sentinel-recruit partitions must not inherit node n-1's
-            # in-flight count from the clipped gather (see step_reconfig)
-            kk = xp.where(recruit < n, xp.maximum(kk, 1), 1)
-            rate = xp.minimum(xp.int32(_REB_SCALE),
-                              xp.int32(bandwidth_fp) // kk)
+            rate = share_rate(counts, recruit)
 
         with stage(xp, "lark_protocols"):
             dt_i = t_clamp - now                              # (B,) int32
@@ -930,9 +967,8 @@ def _make_step(xp, dt_fn, advance, succ, *, n: int, P: int, rf: int,
                                        xp.arange(rf, dtype=xp.int32)
                                        [None, None, :], xp.int32(rf)),
                               axis=2)
-                node = succ[xp.arange(P, dtype=xp.int32)[None, :],
-                            xp.clip(rank, 0, rf - 1)]
-                recruit = xp.where(loss, node, recruit)
+                recruit = xp.where(loss, _rank_node(xp, succ, rank, rf),
+                                   recruit)
         with stage(xp, "lark_protocols"):
             qdn, qt0, qev, qhist = quorum_transitions(
                 t_clamp, qmaj, qreb, qdn, qt0, qev, qhist)
@@ -965,7 +1001,7 @@ def _make_step(xp, dt_fn, advance, succ, *, n: int, P: int, rf: int,
         in_roster = xp.zeros(up_succ.shape, dtype=bool)
         for j in range(rf):
             in_roster = in_roster | (lanes_n[None, None, :]
-                                     == roster[:, :, j, None])
+                                     == roster[:, :, j:j + 1])
         slot = xp.arange(rf, dtype=xp.int32)
         new_rank = xp.full(rup.shape[:2], n, dtype=xp.int32)
         took = xp.zeros(rup.shape[:2], dtype=bool)
@@ -1031,14 +1067,7 @@ def _make_step(xp, dt_fn, advance, succ, *, n: int, P: int, rf: int,
             with stage(xp, "lark_node_counts"):
                 inflight = (qreb > 0) & (recruit < n)
                 counts = cnt_fn(recruit, inflight)            # (B, n)
-                k = xp.take_along_axis(counts,
-                                       xp.clip(recruit, 0, n - 1), axis=1)
-                # sentinel-recruit partitions must not inherit node n-1's
-                # in-flight count from the clipped gather: no known
-                # ingest node means no contention
-                k = xp.where(recruit < n, xp.maximum(k, 1), 1)
-                rate = xp.minimum(xp.int32(_REB_SCALE),
-                                  xp.int32(bandwidth_fp) // k)
+                rate = share_rate(counts, recruit)
         with stage(xp, "lark_protocols"):
             dt_i = t_clamp - now                              # (B,) int32
             lpt, qpt, qreb, qdn, qhist, qmaj_prev, rem0 = interval_pause(
@@ -1057,7 +1086,7 @@ def _make_step(xp, dt_fn, advance, succ, *, n: int, P: int, rf: int,
         with stage(xp, "lark_rank_gather"):
             up_succ = up[:, succ]                             # (B, P, n)
         with stage(xp, "lark_roster"):
-            rup = xp.take_along_axis(up_succ, roster, axis=2)  # (B, P, rf)
+            rup = _seat_up(xp, up_succ, roster)               # (B, P, rf)
             loss_any = xp.any(qrep & ~rup, axis=2)
 
             # -- recruit: every down roster member is replaced by the
@@ -1073,8 +1102,7 @@ def _make_step(xp, dt_fn, advance, succ, *, n: int, P: int, rf: int,
         # with no candidate leaves the seat — and the ingest node —
         # unknown until late recruitment fills it.
         with stage(xp, "lark_node_counts"):
-            new_node = succ[xp.arange(P, dtype=xp.int32)[None, :],
-                            xp.clip(new_rank, 0, n - 1)]
+            new_node = _rank_node(xp, succ, new_rank, n)
             recruit = xp.where(took, new_node,
                                xp.where(loss_any, xp.int32(n), recruit))
 
@@ -1103,7 +1131,7 @@ def _make_step(xp, dt_fn, advance, succ, *, n: int, P: int, rf: int,
             qdn, qt0, qev, qhist = quorum_transitions(
                 t_clamp, qmaj, qreb, qdn, qt0, qev, qhist)
         with stage(xp, "lark_roster"):
-            qrep = xp.take_along_axis(up_succ, roster, axis=2)
+            qrep = _seat_up(xp, up_succ, roster)
         with stage(xp, "lark_protocols"):
             if hermes:
                 hstate = hermes_post(t_clamp, lark, repm, hstate)
@@ -1156,7 +1184,7 @@ def _make_step(xp, dt_fn, advance, succ, *, n: int, P: int, rf: int,
         with stage(xp, "lark_rank_gather"):
             up_succ = up[:, succ]                             # (B, P, n)
         with stage(xp, "lark_roster"):
-            rup = xp.take_along_axis(up_succ, roster, axis=2)  # (B, P, rf)
+            rup = _seat_up(xp, up_succ, roster)               # (B, P, rf)
             loss_any = xp.any(qrep & ~rup, axis=2)
             roster, new_rank, took = recruit_roster(up_succ, rup, roster)
 
@@ -1178,11 +1206,7 @@ def _make_step(xp, dt_fn, advance, succ, *, n: int, P: int, rf: int,
                 out_t = dt_fn(upw, full, roster, recruit, inflight)
             counts = out_t[6 + ne]
             with stage(xp, "lark_node_counts"):
-                k = xp.take_along_axis(counts,
-                                       xp.clip(recruit, 0, n - 1), axis=1)
-                k = xp.where(recruit < n, xp.maximum(k, 1), 1)
-                rate = xp.minimum(xp.int32(_REB_SCALE),
-                                  xp.int32(bandwidth_fp) // k)
+                rate = share_rate(counts, recruit)
         lark, qmaj, ldr, lfull = out_t[:4]
         crepsw = out_t[5 + ne]
         repm = out_t[5] if hermes else None
@@ -1204,8 +1228,7 @@ def _make_step(xp, dt_fn, advance, succ, *, n: int, P: int, rf: int,
         with stage(xp, "lark_protocols"):
             qreb = xp.where(loss_any, rebuild_ticks[None, :], qreb)
         with stage(xp, "lark_node_counts"):
-            new_node = succ[xp.arange(P, dtype=xp.int32)[None, :],
-                            xp.clip(new_rank, 0, n - 1)]
+            new_node = _rank_node(xp, succ, new_rank, n)
             recruit = xp.where(took, new_node,
                                xp.where(loss_any, xp.int32(n), recruit))
 
@@ -1220,7 +1243,7 @@ def _make_step(xp, dt_fn, advance, succ, *, n: int, P: int, rf: int,
             qdn, qt0, qev, qhist = quorum_transitions(
                 t_clamp, qmaj, qreb, qdn, qt0, qev, qhist)
         with stage(xp, "lark_roster"):
-            qrep = xp.take_along_axis(up_succ, roster, axis=2)
+            qrep = _seat_up(xp, up_succ, roster)
         with stage(xp, "lark_protocols"):
             if hermes:
                 hstate = hermes_post(t_clamp, lark, repm, hstate)

@@ -10,11 +10,14 @@ import jax.numpy as jnp
 from hypothesis import given, settings, strategies as st
 
 from repro.core.availability_batched import simulate_availability_batched
-from repro.core.downtime_batched import (SIZE_DISTS, _hist_add,
+from repro.core.downtime_batched import (SIZE_DISTS, _count_at,
+                                         _hist_add,
                                          _partition_rebuild_ticks,
+                                         _rank_node, _seat_up,
                                          partition_sizes_gib,
                                          simulate_downtime_batched)
 from repro.core.scenarios import get_scenario, scenario_names
+from repro.core.succession import succession_matrix_fast
 from repro.kernels.ops import (PAC_BACKENDS, downtime_eval_batch,
                                rebuild_node_counts)
 
@@ -599,3 +602,57 @@ def test_shard_map_path_identical_with_bandwidth_contention():
         assert np.array_equal(plain.trajectory[k], mesh1.trajectory[k]), k
     assert plain.pause_quorum == mesh1.pause_quorum
     assert np.array_equal(plain.hist_quorum, mesh1.hist_quorum)
+
+
+# ---------------------------------------------------------------------------
+# per-(trial, partition) lookups: lane compares equal the indexed forms
+# ---------------------------------------------------------------------------
+
+def _lookup_inputs(n, rf, seed):
+    """Random step-shaped inputs at B=4, P=192: an up mask in rank space,
+    rosters of distinct ranks, per-node counts, recruits with the
+    sentinel n, and ranks at and past both clip edges."""
+    rng = np.random.default_rng(seed)
+    B, P = 4, 192
+    up_succ = rng.random((B, P, n)) < 0.7
+    roster = np.argsort(rng.random((B, P, n)), axis=2)[:, :, :rf]
+    roster[0, :, 0] = n - 1                  # the last rank is a seat too
+    counts = rng.integers(0, 50, (B, n)).astype(np.int32)
+    recruit = rng.integers(0, n + 1, (B, P)).astype(np.int32)
+    recruit[:, ::5] = n                      # no known ingest node
+    recruit[1, :n] = np.arange(n)            # every node at least once
+    rank = rng.integers(-1, n + 2, (B, P)).astype(np.int32)
+    rank[0, :6] = [0, rf - 1, rf, n - 1, n, -1]
+    succ = succession_matrix_fast(P, range(n), seed=seed).astype(np.int32)
+    return (up_succ, roster.astype(np.int32), counts, recruit, rank, succ)
+
+
+@pytest.mark.parametrize("xp", [np, jnp], ids=["numpy", "jnp"])
+@pytest.mark.parametrize("rf", [2, 3])
+@pytest.mark.parametrize("n", [31, 155])
+@pytest.mark.parametrize("lookup", ["seat_up", "count_at", "rank_node"])
+def test_lane_lookups_equal_the_gathers_they_replace(lookup, n, rf, xp):
+    up_succ, roster, counts, recruit, rank, succ = _lookup_inputs(
+        n, rf, seed=1000 * n + rf)
+    if lookup == "seat_up":
+        got = [_seat_up(xp, xp.asarray(up_succ), xp.asarray(roster))]
+        want = [np.take_along_axis(up_succ, roster, axis=2)]
+    elif lookup == "count_at":
+        got = [_count_at(xp, xp.asarray(counts), xp.asarray(recruit))]
+        gathered = np.take_along_axis(counts, np.clip(recruit, 0, n - 1),
+                                      axis=1)
+        want = [np.where(recruit < n, gathered, 0)]
+        # the contention divisor the steps derive from it is unchanged
+        assert np.array_equal(
+            np.where(recruit < n, np.maximum(np.asarray(got[0]), 1), 1),
+            np.where(recruit < n, np.maximum(gathered, 1), 1))
+    else:
+        got = [_rank_node(xp, xp.asarray(succ), xp.asarray(rank), w)
+               for w in (rf, n)]
+        want = [succ[np.arange(succ.shape[0])[None, :],
+                     np.clip(rank, 0, w - 1)] for w in (rf, n)]
+    for g, w in zip(got, want):
+        g = np.asarray(g)
+        assert g.dtype == (np.bool_ if lookup == "seat_up" else np.int32)
+        assert g.shape == w.shape
+        assert np.array_equal(g, w)

@@ -228,3 +228,83 @@ print(sorted(jax_mods() - before))
                        capture_output=True, text=True, timeout=300)
     assert p.returncode == 0, p.stderr
     assert p.stdout.strip().splitlines()[-1] == "[]"
+
+
+def _gathers_under(text, stages):
+    """Names of the stages in `stages` that hold a `stablehlo.gather` in
+    the lowered program `text` (debug info on).  An op is under a stage
+    when its own location names the stage, or when a call op under the
+    stage reaches the function it is in (jnp wraps some ops, such as
+    take_along_axis, in private functions whose ops carry no scope)."""
+    defs = dict(re.findall(r"^(#loc\d+) = (.*)$", text, re.M))
+
+    def names(loc, seen=()):
+        if loc in seen or loc not in defs:
+            return set()
+        body = defs[loc]
+        out = set(re.findall(r'"([^"]*)"\(', body))
+        for ref in re.findall(r"#loc\d+", body):
+            out |= names(ref, seen + (loc,))
+        return out
+
+    def under(loc):
+        return {s for s in stages for name in names(loc)
+                if re.search(rf"(^|/){s}/", name)}
+
+    ops = []                    # (function, op, location)
+    func = None
+    for line in text.splitlines():
+        head = re.match(r"\s*func\.func (?:public |private )?@([\w.$-]+)\(",
+                        line)
+        if head:
+            func = head.group(1)
+            continue
+        loc = re.search(r"loc\((#loc\d+)\)\s*$", line)
+        if func is None or loc is None:
+            continue
+        call = re.search(r"\bcall @([\w.$-]+)\(", line)
+        if call:
+            ops.append((func, ("call", call.group(1)), loc.group(1)))
+        elif "stablehlo.gather" in line:
+            ops.append((func, ("gather",), loc.group(1)))
+
+    # stages each function is reached under, through any chain of calls
+    reach = {}
+    changed = True
+    while changed:
+        changed = False
+        for func, op, loc in ops:
+            if op[0] != "call":
+                continue
+            got = under(loc) | reach.get(func, set())
+            if not got <= reach.get(op[1], set()):
+                reach[op[1]] = reach.get(op[1], set()) | got
+                changed = True
+    found = set()
+    for func, op, loc in ops:
+        if op[0] == "gather":
+            found |= under(loc) | reach.get(func, set())
+    return found
+
+
+LOOKUP_VARIANTS = [v for v in VARIANTS
+                   if v[0] in ("downtime-fixed-bandwidth",
+                               "downtime-reconfig",
+                               "downtime-reconfig-packed", "zoo",
+                               "latency")]
+
+
+@pytest.mark.parametrize("name,engine,knobs,expect", LOOKUP_VARIANTS,
+                         ids=[v[0] for v in LOOKUP_VARIANTS])
+def test_roster_and_node_counts_hold_no_gather(lower_first_chunk, name,
+                                               engine, knobs, expect):
+    """The roster and node-count stages look up per-(trial, partition)
+    values with lane compares, never with a gather: on a TPU v5e a
+    gather with one index an element costs about 10 ns an element.
+    (`up[:, succ]` under lark_rank_gather stays a gather.)"""
+    with pytest.raises(_Lowered) as got:
+        ENTRY[engine](**BASE, **knobs, chunk_steps=4, max_steps=5)
+    text = got.value.text
+    assert "lark_rank_gather" in _gathers_under(text, ("lark_rank_gather",))
+    assert _gathers_under(text, ("lark_roster", "lark_node_counts")) \
+        == set()
