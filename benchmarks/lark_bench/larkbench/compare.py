@@ -90,6 +90,19 @@ def as_view(ref: dict) -> dict:
             "pooled": pooled, "trajectory": ref["trajectory"]}
 
 
+def join(parts: list) -> dict:
+    """One reference output from outputs on consecutive blocks of the
+    sample: per-trial arrays joined along the trials axis (the
+    trajectories' second), everything else from the first block."""
+    def cat(key, xs):
+        if isinstance(xs[0], dict):
+            return {k: cat(key, [x[k] for x in xs]) for k in xs[0]}
+        if isinstance(xs[0], np.ndarray):
+            return np.concatenate(xs, axis=1 if key == "trajectory" else 0)
+        return xs[0]
+    return {k: cat(k, [p[k] for p in parts]) for k in parts[0]}
+
+
 def _rel_gap(got, want) -> float:
     got = np.asarray(got, dtype=np.float64)
     want = np.asarray(want, dtype=np.float64)

@@ -6,13 +6,17 @@ Only what a user sets is set here (the deployment, the scenario, the
 protocols, the seed, the trial count and mesh, backend="pallas",
 packed=True, a step budget, trajectory=True); tile, chunk length and the
 rest stay at the program's defaults.  The stopping rule is switched off
-by a min_ticks above the horizon, so every call runs whole chunks.
+by a min_ticks above the horizon, so every call runs whole chunks.  A
+deployment's own engine knobs (its config's `engine_knobs`) follow, as
+they are written there.
 """
 from __future__ import annotations
 
 import inspect
 
 import numpy as np
+
+from larkbench.spec import SpecError
 
 ENGINES = ("availability", "downtime", "latency")
 
@@ -39,7 +43,9 @@ def chunk_steps(engine: str) -> int:
 
 
 def kwargs(cell: dict, *, seed: int, chunks: int) -> dict:
-    """Keyword arguments of one call that runs `chunks` whole chunks."""
+    """Keyword arguments of one call that runs `chunks` whole chunks; the
+    cell's `engine_knobs` come last, and one that names a key set here is
+    an error, never an override."""
     kw = dict(n=cell["n"], partitions=cell["partitions"], rf=cell["rf"],
               p=cell["p"], downtime=cell["downtime"], trials=cell["trials"],
               seed=seed, max_ticks=cell["horizon"],
@@ -66,6 +72,12 @@ def kwargs(cell: dict, *, seed: int, chunks: int) -> dict:
         kw.update(key_zipf=cell["key_zipf"], read_frac=cell["read_frac"],
                   requests_per_tick=cell["requests_per_tick"],
                   slo_ticks=cell["slo_ticks"])
+    knobs = cell.get("engine_knobs", {})
+    clash = sorted(set(knobs) & set(kw))
+    if clash:
+        raise SpecError(f"engine_knobs {clash} would override what the "
+                        f"harness sets")
+    kw.update(knobs)
     return kw
 
 

@@ -3,10 +3,30 @@
 (metrics/), kernel byte counts (kernels/) and the device peaks (peaks/).
 
 Every piece is a file of its own, so a new cell, mix, metric, kernel or
-device is added by adding files; nothing here names one.
+device is added by adding files; nothing here names one.  A config and a
+mix make one cell at most, so a mix laid out for a trials mesh is a file
+of its own that states its `"chips"`; a workload that asks for another
+count is a SpecError.
+
+A deployment whose engine needs more than the harness sets brings it in
+its config file, also by adding files:
+
+  "engine_knobs": {...}      passed to the engine's entry point as they
+                             are written (JSON lists stay lists), after
+                             the keys program.kwargs sets; a knob that
+                             names one of those keys is a SpecError
+  "reference": {"<engine>": "<module>"}
+                             the plain reference of that engine is
+                             larkbench/reference/<module>.py, a new file
+                             that may import from reference/common.py
+                             and copy what it changes; without the entry
+                             it is larkbench/reference/<engine>.py, and
+                             a named module that is not there is a
+                             SpecError before any run
 """
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 import os
@@ -34,8 +54,9 @@ def benchmark(checkout: str = CHECKOUT) -> dict:
 
 def cell(name: str, checkout: str = CHECKOUT, bench: dict = None) -> dict:
     """Everything one cell runs with, flattened: the deployment's sizes,
-    the traffic's engine and knobs, the chips, the horizon and the trial
-    count (trials per chip x chips)."""
+    the traffic's engine and knobs, the chips, the horizon, the trial
+    count (trials per chip x chips) and the trials the reference runs
+    (the mix's `reference_trials` a chip, at most every trial)."""
     bench = bench or benchmark(checkout)
     work = {w["name"]: w for w in bench["workloads"]}
     if name not in work:
@@ -47,6 +68,9 @@ def cell(name: str, checkout: str = CHECKOUT, bench: dict = None) -> dict:
     here = os.path.join(checkout, os.path.relpath(BENCH_DIR, CHECKOUT))
     traffic = _load_json(os.path.join(here, "traffic",
                                       w["traffic"] + ".json"))
+    if traffic.get("chips", w["chips"]) != w["chips"]:
+        raise SpecError(f"traffic {w['traffic']!r} is for {traffic['chips']} "
+                        f"chips; workload {name!r} asks for {w['chips']}")
     out = {k: v for k, v in conf.items()
            if k not in ("assumed", "reduced", "guarantees", "source",
                         "deployment", "name")}
@@ -54,10 +78,24 @@ def cell(name: str, checkout: str = CHECKOUT, bench: dict = None) -> dict:
     out["name"] = name
     out["chips"] = w["chips"]
     out["trials"] = conf["trials_per_chip"] * w["chips"]
+    out["reference_trials"] = min(traffic["reference_trials"] * w["chips"],
+                                  out["trials"])
     out["horizon"] = conf["horizon_ticks"][traffic["horizon"]]
     out["limits"] = _load_json(os.path.join(here, "limits",
                                             name + ".json"))
     return out
+
+
+def reference(cell: dict, *, load: bool = True):
+    """The plain reference module of the cell's engine: the one its
+    config names under `reference`, else larkbench.reference.<engine>;
+    with load=False only its name, once its file is found."""
+    engine = cell["engine"]
+    full = "larkbench.reference." + cell.get("reference", {}).get(engine,
+                                                                   engine)
+    if importlib.util.find_spec(full) is None:
+        raise SpecError(f"no reference module {full} for engine {engine!r}")
+    return importlib.import_module(full) if load else full
 
 
 def _module(path: str, name: str):

@@ -19,9 +19,6 @@ arithmetic).
 from __future__ import annotations
 
 import functools
-import glob
-import os
-import tempfile
 
 from larkbench import op_names, trace
 
@@ -32,8 +29,6 @@ CALL_SPAN = "lark.call"
 SETUP_SPANS = ("lark.setup", "lark.chunk_program")
 DRAIN_SPAN = "lark.drain"
 SPAN_PREFIX = "lark."
-#: run.py's prefix for the temporary directory that holds the trace
-TRACE_DIR_PREFIX = "lark_bench_trace_"
 
 
 def stage_of(op_name) -> str | None:
@@ -81,29 +76,10 @@ def read(path: str) -> dict:
                          op_names.read(path))
 
 
-def find_xplane(ctx) -> str | None:
-    """The run's trace file: ctx["xplane"] where the harness passes it;
-    else the newest of run.py's trace directories under the temp
-    directory whose window lasts exactly as long as the summary's."""
-    if ctx.get("xplane"):
-        return ctx["xplane"]
-    dirs = sorted(glob.glob(os.path.join(tempfile.gettempdir(),
-                                         TRACE_DIR_PREFIX + "*")),
-                  key=os.path.getmtime, reverse=True)
-    for d in dirs[:3]:
-        try:
-            path = trace.xplane_path(d)
-        except FileNotFoundError:
-            continue
-        window = read(path)["window"]
-        if window and window[1] - window[0] == ctx["summary"]["window_ns"]:
-            return path
-    return None
-
-
 def of(ctx) -> dict | None:
-    """The reduction of the run's trace, None without one."""
-    path = find_xplane(ctx)
+    """The reduction of the run's trace (ctx["xplane"], which run.py
+    passes), None without one."""
+    path = ctx.get("xplane")
     return read(path) if path else None
 
 

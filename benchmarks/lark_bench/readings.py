@@ -35,10 +35,7 @@ def main(argv=None) -> int:
     except run.NoDevice as e:
         run.log(f"readings: {e}")
         return 3
-    import importlib
-
     from larkbench import compare, program
-    ref_mod = importlib.import_module(f"larkbench.reference.{cell['engine']}")
     cs = program.chunk_steps(cell["engine"])
     for seed in (int(s) for s in args.seeds.split(",")):
         t = time.perf_counter()
@@ -46,15 +43,13 @@ def main(argv=None) -> int:
         view = program.view(cell, res)
         sample = compare.sample_trials(seed, cell["trials"],
                                        cell["reference_trials"])
-        kw = dict(seed=seed, trials=sample, chunks=cell["chunks_per_call"],
-                  chunk_steps=cs)
-        ref = ref_mod.simulate(cell, **kw)
+        ref = run.reference(cell, seed, sample)
         lower = compare.readings(
             view, ref, sample, partitions=cell["partitions"],
             horizon=cell["horizon"], calls_differ=0,
             failed=compare.failed_trials(view, chunk_steps=cs),
             min_waves=cell.get("min_restart_waves", 0))
-        ctrl = ref_mod.simulate(cell, acks=cell["rf"] - 1, **kw)
+        ctrl = run.reference(cell, seed, sample, acks=cell["rf"] - 1)
         upper = compare.readings(
             compare.as_view(ctrl), ref, list(range(len(sample))),
             partitions=cell["partitions"], horizon=cell["horizon"],

@@ -9,14 +9,15 @@ one traffic mix (traffic/) on 1 or 4 chips.  A run:
 
   set-up   process start, compile cache, two warm-up calls of the
            cell's engine entry point for one chunk at the cell's shapes
-           (the first compiles, or loads from the cache); its end is
-           `setup_s`
+           (the first compiles, or loads from the cache; on a trials mesh
+           the second runs two chunks); its end is `setup_s`
   window   calls of that entry point with the same seed and shapes, each
            `chunks_per_call` whole chunks, until `--seconds` have passed
            (`--trace 1`: one call, under the profiler)
   check    the first call's result against the plain reference
-           (larkbench/reference/) on a sample of trials drawn from the
-           seed; every later call must equal the first bit for bit
+           (larkbench/reference/<engine>.py, or the module the cell's
+           config names) on a sample of trials drawn from the seed;
+           every later call must equal the first bit for bit
 
 The last line of standard output is one JSON object: correct, attempted,
 failed, metrics (end-to-end with --trace 0, per-layer with --trace 1),
@@ -30,11 +31,14 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import concurrent.futures  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
+
+import numpy as np  # noqa: E402
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 CHECKOUT = os.path.dirname(os.path.dirname(BENCH_DIR))
@@ -42,6 +46,15 @@ CACHE_DIRNAME = ".jax-compilation-cache"
 # one-chunk calls before the window: the first compiles (or loads from
 # the cache) every program a call runs, the second runs from the cache
 WARM_UP_CALLS = 2
+
+
+def warm_up_chunks(cell: dict, i: int) -> int:
+    """Chunks of warm-up call i.  On a trials mesh the last one runs two:
+    a later chunk there takes its carry in the mesh's layout, which is a
+    program of its own, so one-chunk calls would leave it to the window."""
+    if i == WARM_UP_CALLS - 1 and cell["chips"] > 1:
+        return min(2, cell["chunks_per_call"])
+    return 1
 
 
 class NoDevice(Exception):
@@ -119,7 +132,8 @@ def drive(cell, seed, seconds, trace_dir):
                 opts.python_tracer_level = 0
                 opts.enable_hlo_proto = False
                 jax.profiler.start_trace(trace_dir, profiler_options=opts)
-            chunks = 1 if warm else cell["chunks_per_call"]
+            chunks = warm_up_chunks(cell, i) if warm \
+                else cell["chunks_per_call"]
             span = trace.WARM_UP_SPAN if warm else trace.WINDOW_SPAN
             t = time.perf_counter()
             with jax.profiler.TraceAnnotation(span):
@@ -142,11 +156,31 @@ def drive(cell, seed, seconds, trace_dir):
     return results, setup_end, window_end - setup_end, lines
 
 
+def reference(cell, seed, sample, **kw):
+    """The plain reference on the sampled trials, in one block a chip,
+    each block on its own chip and all at once (`kw`: the control's
+    broken guarantee)."""
+    import jax
+
+    from larkbench import compare, program, spec
+    ref_mod = spec.reference(cell)
+    kw.update(seed=seed, chunks=cell["chunks_per_call"],
+              chunk_steps=program.chunk_steps(cell["engine"]))
+    if cell["chips"] == 1:
+        return ref_mod.simulate(cell, trials=sample, **kw)
+
+    def block(device, trials):
+        with jax.default_device(device):
+            return ref_mod.simulate(cell, trials=trials, **kw)
+    blocks = np.array_split(np.asarray(sample), cell["chips"])
+    with concurrent.futures.ThreadPoolExecutor(len(blocks)) as pool:
+        parts = list(pool.map(block, jax.devices()[:len(blocks)], blocks))
+    return compare.join(parts)
+
+
 def check(cell, seed, results):
     """Compare the window's results with the reference: (correct,
     attempted, failed, rows of [name, value, limit])."""
-    import importlib
-
     from larkbench import compare, program
     cs = program.chunk_steps(cell["engine"])
     views = [program.view(cell, r) for r in results]
@@ -154,10 +188,8 @@ def check(cell, seed, results):
     differ = sum(1 for r in results[1:] if compare.diff(results[0], r))
     sample = compare.sample_trials(seed, cell["trials"],
                                    cell["reference_trials"])
-    ref_mod = importlib.import_module(f"larkbench.reference.{cell['engine']}")
     t = time.perf_counter()
-    ref = ref_mod.simulate(cell, seed=seed, trials=sample,
-                           chunks=cell["chunks_per_call"], chunk_steps=cs)
+    ref = reference(cell, seed, sample)
     log(f"# reference: {len(sample)} trials in "
         f"{time.perf_counter() - t!r} s")
     values = compare.readings(views[0], ref, sample,
@@ -174,9 +206,10 @@ def layer_metrics(bench, cell, device, trace_dir, steps):
     a reader that finds nothing returns None and the metric is left out."""
     from larkbench import spec, trace
     per_dev = cell["trials"] // cell["chips"]
-    summ = trace.summarize(trace.read(trace.xplane_path(trace_dir)),
+    xplane = trace.xplane_path(trace_dir)
+    summ = trace.summarize(trace.read(xplane),
                            spec.kernel_classifier(cell, per_dev))
-    ctx = {"summary": summ, "steps": steps, "cell": cell,
+    ctx = {"summary": summ, "steps": steps, "cell": cell, "xplane": xplane,
            "peaks": spec.peaks(device["kind"]),
            "kernel_bytes": {k.KIND: k.bytes_per_call(cell, per_dev)
                             for k in spec.kernel_counts()}}
@@ -196,8 +229,9 @@ def layer_metrics(bench, cell, device, trace_dir, steps):
 def prepare(workload: str, *, require_tpu: bool = True,
             cells: str = CHECKOUT):
     """(cell, device): the cell's definition from the checkout `cells`
-    (tests point it at a small one), the compile cache, and the device
-    stamp; raises NoDevice without the program or the chips."""
+    (tests point it at a small one), its reference module's file found,
+    the compile cache, and the device stamp; raises NoDevice without the
+    program or the chips."""
     src = os.path.join(CHECKOUT, "src")
     if not os.path.isdir(os.path.join(src, "repro")):
         raise NoDevice(f"no program under {src}")
@@ -206,6 +240,7 @@ def prepare(workload: str, *, require_tpu: bool = True,
             sys.path.insert(0, p)
     from larkbench import spec
     cell = spec.cell(workload, cells)
+    spec.reference(cell, load=False)
     cache = enable_cache()
     import jax
     device = device_stamp(jax, cell["chips"], require_tpu=require_tpu)

@@ -34,11 +34,10 @@ from larkbench import compare, program, spec  # noqa: E402
 CELLS = ("rf2-avail-iid", "rf2-zoo-rolling", "rf3-latency-ycsb-a")
 
 
-@pytest.fixture(scope="module")
-def small(tmp_path_factory):
-    """A checkout holding the benchmark's cells cut to 31 nodes, 128
-    partitions and 8 trials, each call one chunk, every trial sampled."""
-    root = tmp_path_factory.mktemp("small")
+def make_small(root):
+    """A checkout at `root` holding the benchmark's cells cut to 31 nodes,
+    128 partitions and 8 trials a chip, each call one chunk, 8 trials
+    sampled (every trial of a one-chip cell)."""
     here = root / "benchmarks" / "lark_bench"
     shutil.copytree(BENCH, here, ignore=shutil.ignore_patterns(
         "__pycache__", "tests", "testdata"))
@@ -55,6 +54,11 @@ def small(tmp_path_factory):
         path.write_text(json.dumps(mix))
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     return str(root)
+
+
+@pytest.fixture(scope="module")
+def small(tmp_path_factory):
+    return make_small(tmp_path_factory.mktemp("small"))
 
 
 @pytest.fixture(autouse=True)

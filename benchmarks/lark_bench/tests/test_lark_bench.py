@@ -246,18 +246,43 @@ def test_benchmark_file_shape():
     for c in bench["configs"]:
         assert os.path.exists(os.path.join(CHECKOUT, c["file"]))
         assert c["file"].startswith(bench["paths"][0] + "/")
+    pairs = [(w["config"], w["traffic"]) for w in bench["workloads"]]
+    assert len(set(pairs)) == len(pairs)
+
+
+def test_mix_for_a_mesh_runs_on_its_chips(tmp_path):
+    """A mix that states its chips runs only on that many; the four-chip
+    cell's mix is the zoo's, laid out for four."""
+    def mix(name):
+        with open(os.path.join(BENCH, "traffic", name + ".json")) as fh:
+            return json.load(fh)
+    zoo, mesh = mix("zoo-rolling"), mix("zoo-rolling-mesh")
+    assert mesh.pop("chips") == 4 and mesh == zoo
+    bench = spec.benchmark()
+    for w in bench["workloads"]:
+        if w["traffic"] == "zoo-rolling-mesh":
+            w["chips"] = 1
+    root = tmp_path / "checkout"
+    shutil.copytree(BENCH, root / "benchmarks" / "lark_bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    spec.cell("rf2-zoo-rolling", str(root))
+    with pytest.raises(spec.SpecError, match="is for 4 chips"):
+        spec.cell("rf2-zoo-rolling-4chip", str(root))
 
 
 def test_new_cell_by_adding_files_only(tmp_path):
     """A cell is added by a config, a traffic mix and a limits file and
-    one BENCHMARK.json entry; no code changes."""
+    one BENCHMARK.json entry; no code changes.  The config may bring its
+    engine knobs and name its reference module."""
     root = tmp_path / "checkout"
     shutil.copytree(BENCH, root / "benchmarks" / "lark_bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
     bench = spec.benchmark()
     here = root / "benchmarks" / "lark_bench"
     conf = json.loads((here / "configs" / "sc-rf2-n155.json").read_text())
-    conf.update(name="sc-rf2-n93", n=93)
+    conf.update(name="sc-rf2-n93", n=93, engine_knobs={"racks": [0, 1, 2]},
+                reference={"availability": "availability"})
     (here / "configs" / "sc-rf2-n93.json").write_text(json.dumps(conf))
     mix = json.loads((here / "traffic" / "avail-iid.json").read_text())
     mix.update(scenario="rack-pairs",
@@ -277,6 +302,148 @@ def test_new_cell_by_adding_files_only(tmp_path):
     c = spec.cell("n93-avail-rack", str(root))
     assert c["n"] == 93 and c["scenario_knobs"] == {"pair_fail_prob": 0.5}
     assert c["engine"] == "availability" and c["chips"] == 1
+    assert c["engine_knobs"] == {"racks": [0, 1, 2]}
+    assert spec.reference(c).__name__ == "larkbench.reference.availability"
+
+
+def test_warm_up_builds_a_mesh_cells_later_chunk():
+    """On a trials mesh the last warm-up call runs a second chunk, whose
+    program the window's calls would otherwise build; one-chip cells warm
+    up with one-chunk calls."""
+    import run
+    for w in spec.benchmark()["workloads"]:
+        c = spec.cell(w["name"])
+        got = [run.warm_up_chunks(c, i) for i in range(run.WARM_UP_CALLS)]
+        assert got == ([1, 2] if c["chips"] > 1 else [1, 1]), w["name"]
+
+
+# -- a deployment's own engine knobs and reference -----------------------------
+
+#: each accepted cell's call of its engine at seed 2**32 + 17, with its
+#: own chunks per call, as the harness has made it since it was written
+GOLDEN_KWARGS = {
+    "rf2-avail-iid": {
+        "n": 155, "partitions": 4096, "rf": 2, "p": 0.001, "downtime": 10,
+        "trials": 64, "seed": 4294967313, "max_ticks": 3000000,
+        "min_ticks": 3000001, "backend": "pallas", "packed": True,
+        "devices": 1, "trajectory": True, "max_steps": 3073},
+    "rf2-zoo-rolling": {
+        "n": 155, "partitions": 4096, "rf": 2, "p": 0.001, "downtime": 10,
+        "trials": 64, "seed": 4294967313, "max_ticks": 1000000,
+        "min_ticks": 1000001, "backend": "pallas", "packed": True,
+        "devices": 1, "trajectory": True, "max_steps": 1537,
+        "restart_period": 2000, "wave_width": 1, "dupres_ticks": 1,
+        "hist_bins": 16, "rebuild_model": "reconfig",
+        "node_bandwidth_gibps": 1.0, "rebuild_ticks_per_gib": 100,
+        "size_dist": "zipf", "size_skew": 1.0,
+        "engines": ("lark", "quorum", "hermes", "spinnaker"),
+        "lease_ticks": 40, "view_change_ticks": 200},
+    "rf3-latency-ycsb-a": {
+        "n": 155, "partitions": 4096, "rf": 3, "p": 0.001, "downtime": 10,
+        "trials": 64, "seed": 4294967313, "max_ticks": 1000000,
+        "min_ticks": 1000001, "backend": "pallas", "packed": True,
+        "devices": 1, "trajectory": True, "max_steps": 2049,
+        "pair_fail_prob": 0.5, "dupres_ticks": 1, "hist_bins": 16,
+        "rebuild_model": "fixed", "node_bandwidth_gibps": 1.0,
+        "rebuild_steps": 100, "key_zipf": 0.99, "read_frac": 0.5,
+        "requests_per_tick": 32.0, "slo_ticks": 8},
+}
+GOLDEN_REFERENCE = {"rf2-avail-iid": "larkbench.reference.availability",
+                    "rf2-zoo-rolling": "larkbench.reference.downtime",
+                    "rf3-latency-ycsb-a": "larkbench.reference.latency"}
+
+
+@pytest.mark.parametrize("name", sorted(GOLDEN_KWARGS))
+def test_accepted_cells_call_as_before(name):
+    """The engine's keyword arguments, in order, and the reference module
+    of each accepted cell."""
+    from larkbench import program
+    c = spec.cell(name)
+    kw = program.kwargs(c, seed=2 ** 32 + 17, chunks=c["chunks_per_call"])
+    assert list(kw.items()) == list(GOLDEN_KWARGS[name].items())
+    assert spec.reference(c).__name__ == GOLDEN_REFERENCE[name]
+
+
+def _deployment(tmp_path, **extra):
+    """A checkout whose one new cell, n93-avail, runs a new config with the
+    keys `extra` on the avail-iid mix; added as files only."""
+    root = tmp_path / "checkout"
+    shutil.copytree(BENCH, root / "benchmarks" / "lark_bench",
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    here = root / "benchmarks" / "lark_bench"
+    conf = json.loads((here / "configs" / "sc-rf2-n155.json").read_text())
+    conf.update(name="sc-rf2-n93", n=93, **extra)
+    (here / "configs" / "sc-rf2-n93.json").write_text(json.dumps(conf))
+    (here / "limits" / "n93-avail.json").write_text(
+        (here / "limits" / "rf2-avail-iid.json").read_text())
+    bench = spec.benchmark()
+    bench["configs"].append({"name": "sc-rf2-n93", "source": "x",
+                             "file": "benchmarks/lark_bench/configs/"
+                                     "sc-rf2-n93.json",
+                             "reduced": [], "why": "x"})
+    bench["workloads"].append({"name": "n93-avail", "config": "sc-rf2-n93",
+                               "traffic": "avail-iid", "chips": 1,
+                               "why": "x"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return str(root)
+
+
+def test_deployment_brings_knobs_and_reference(tmp_path, monkeypatch):
+    """A config's `engine_knobs` reach the entry point after the harness's
+    keys, as written; its `reference` names the module that run.check
+    calls, here a stub."""
+    import importlib.machinery
+    import types
+
+    import run
+    from larkbench import program
+    racks = [i % 3 for i in range(93)]
+    root = _deployment(tmp_path, engine_knobs={"racks": racks},
+                       reference={"availability": "stub_n93"})
+    c = spec.cell("n93-avail", root)
+    assert c["engine_knobs"] == {"racks": racks}
+    seen = {}
+    monkeypatch.setattr(program, "chunk_steps", lambda engine: 512)
+    monkeypatch.setattr(program, "entry", lambda engine: lambda **kw:
+                        seen.update(engine=engine, kw=kw))
+    program.call(c, seed=5, chunks=1)
+    assert seen["engine"] == "availability"
+    assert list(seen["kw"])[-1] == "racks" and seen["kw"]["racks"] == racks
+    assert seen["kw"]["n"] == 93 and seen["kw"]["seed"] == 5
+
+    stub = types.ModuleType("larkbench.reference.stub_n93")
+    stub.__spec__ = importlib.machinery.ModuleSpec(stub.__name__, None)
+    calls = []
+
+    def simulate(cell, *, seed, trials, chunks, chunk_steps):
+        calls.append((cell["name"], seed, list(trials), chunks))
+        return dict(_ref(S=len(trials)), partitions=cell["partitions"])
+    stub.simulate = simulate
+    monkeypatch.setitem(sys.modules, stub.__name__, stub)
+    assert spec.reference(c) is stub
+    monkeypatch.setattr(program, "view", lambda cell, res: res)
+    view = compare.as_view(dict(_ref(S=c["trials"]),
+                                partitions=c["partitions"]))
+    ok, attempted, failed, rows = run.check(c, 7, [view])
+    assert calls == [("n93-avail", 7, list(range(c["trials"])),
+                      c["chunks_per_call"])]
+    assert ok and attempted == c["trials"] and failed == 0, rows
+
+
+@pytest.mark.parametrize("key", ["seed", "trials", "devices", "rf",
+                                 "max_steps", "backend"])
+def test_knob_that_collides_is_refused(tmp_path, key):
+    from larkbench import program
+    root = _deployment(tmp_path, engine_knobs={key: 1})
+    with pytest.raises(spec.SpecError, match=key):
+        program.kwargs(spec.cell("n93-avail", root), seed=5, chunks=1)
+
+
+def test_missing_reference_is_refused_before_any_run(tmp_path):
+    import run
+    root = _deployment(tmp_path, reference={"availability": "no_such_ref"})
+    with pytest.raises(spec.SpecError, match="no_such_ref"):
+        run.prepare("n93-avail", require_tpu=False, cells=root)
 
 
 # -- no chip, no result ------------------------------------------------------

@@ -244,12 +244,13 @@ def test_op_names_read_from_event_metadata(tmp_path):
         "%fusion.2 = s32[262144] fusion()": "jit(_chunk)/lark_roster/gather:"}
 
 
-def test_trace_file_is_found_by_its_window(tmp_path, monkeypatch):
-    """Without ctx["xplane"], the reader finds run.py's trace directory
-    under the temp directory, and only where the window matches."""
+def test_trace_file_is_found_by_its_window(tmp_path):
+    """The readers read the trace file the harness names in
+    ctx["xplane"], window and spans as recorded, and nothing without
+    one.  (The name dates from a search by window that went; the
+    repository's test run collects this test by it.)"""
     import jax
-    monkeypatch.setattr("tempfile.tempdir", str(tmp_path))
-    d = tmp_path / (stages.TRACE_DIR_PREFIX + "x")
+    d = tmp_path / "trace"
     jax.profiler.start_trace(str(d))
     with jax.profiler.TraceAnnotation(trace.WINDOW_SPAN):
         with jax.profiler.TraceAnnotation("lark.call", call=4,
@@ -260,12 +261,10 @@ def test_trace_file_is_found_by_its_window(tmp_path, monkeypatch):
     jax.profiler.stop_trace()
     path = trace.xplane_path(str(d))
     lo, hi = trace.read(path)["window"]
-    ctx = {"steps": 1, "summary": {"window_ns": hi - lo}}
-    assert stages.find_xplane(ctx) == path
-    assert stages.find_xplane(dict(ctx, xplane="given")) == "given"
-    assert stages.find_xplane({"summary": {"window_ns": hi - lo + 1}}) \
-        is None
-    tr = stages.read(path)
+    ctx = {"steps": 1, "summary": {"window_ns": hi - lo}, "xplane": path}
+    tr = stages.of(ctx)
+    assert tr is stages.read(path) and tr["window"] == (lo, hi)
+    assert stages.of(dict(ctx, xplane=None)) is None
     spans = {name: args for name, _, _, args in tr["spans"]}
     assert spans["lark.call"] == {"call": 4, "engine": "downtime"}
     assert spans["lark.drain"] == {"call": 4, "chunk": 0}
